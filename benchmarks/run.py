@@ -325,6 +325,9 @@ def main() -> None:
         "needs it)",
     )
     args = ap.parse_args()
+    from repro.utils import use_compile_cache
+
+    use_compile_cache(str(pathlib.Path(__file__).resolve().parents[1]))
     if args.json:
         run_json(args.json, args.only, args.repeats)
     else:

@@ -36,6 +36,7 @@ from repro.core.flash import (  # noqa: F401
     query_ctx,
     reconstruct,
     sdc_lookup,
+    sdc_table,
     to_neighbor_blocks,
     unpack_codes,
 )

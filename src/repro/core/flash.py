@@ -34,6 +34,11 @@ from repro.core import kmeans as km
 from repro.core import pca as pca_mod
 from repro.core import quantize as qz
 
+#: f32 matmuls at full precision: a TPU's default runs them as one bf16
+#: pass, an error as large as the small partial distances that order near
+#: neighbours
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 class FlashCoder(NamedTuple):
     """Fitted Flash coding state (a pytree; static hyperparams via shapes).
@@ -145,7 +150,7 @@ def fit_flash(
     mean = model.mean
 
     fit_rows = min(n, max_fit_sample)
-    z = (sample[:fit_rows] - mean) @ rot  # (n', d_F)
+    z = jnp.matmul(sample[:fit_rows] - mean, rot, precision=_EXACT)  # (n', d_F)
     subs = _split_subspaces(z, m_f, ds)  # (M, n', ds)
 
     codebooks, _ = km.kmeans_fit_batched(key, subs, k=k, iters=kmeans_iters)
@@ -181,13 +186,13 @@ def _partial_dists(subs: jax.Array, codebooks: jax.Array) -> jax.Array:
     """(M, n, ds) vs (M, K, ds) -> per-subspace squared dists (M, n, K)."""
     x2 = jnp.sum(subs * subs, axis=-1, keepdims=True)  # (M, n, 1)
     c2 = jnp.sum(codebooks * codebooks, axis=-1)  # (M, K)
-    xc = jnp.einsum("mnd,mkd->mnk", subs, codebooks)
+    xc = jnp.einsum("mnd,mkd->mnk", subs, codebooks, precision=_EXACT)
     return jnp.maximum(x2 + c2[:, None, :] - 2.0 * xc, 0.0)
 
 
 def encode(coder: FlashCoder, x: jax.Array) -> jax.Array:
     """Encode vectors (n, D) -> codewords (n, M) int32 in [0, K)."""
-    z = (x - coder.mean) @ coder.rot
+    z = jnp.matmul(x - coder.mean, coder.rot, precision=_EXACT)
     subs = _split_subspaces(z, coder.m_f, coder.ds)  # (M, n, ds)
     codes = km.assign_codes_batched(subs, coder.codebooks)  # (M, n)
     return codes.T.astype(jnp.int32)
@@ -215,7 +220,7 @@ def query_ctx(coder: FlashCoder, q: jax.Array) -> FlashQueryCtx:
     Codeword and ADT generation share the same distance computations
     (paper Remark 2): the argmin over the ADT row *is* the codeword.
     """
-    z = (q - coder.mean) @ coder.rot  # (d_F,)
+    z = jnp.matmul(q - coder.mean, coder.rot, precision=_EXACT)  # (d_F,)
     subs = _split_subspaces(z[None, :], coder.m_f, coder.ds)  # (M, 1, ds)
     adt_f = _partial_dists(subs, coder.codebooks)[:, 0, :]  # (M, K)
     tq = qz.TableQuant(coder.dist_min, coder.delta, coder.h_bits)
@@ -250,6 +255,22 @@ def sdc_lookup(coder: FlashCoder, codes_a: jax.Array, codes_b: jax.Array) -> jax
     m_idx = jnp.arange(coder.m_f)
     vals = coder.sdt_q[m_idx, codes_a, codes_b]  # (..., M)
     return jnp.sum(vals, axis=-1)
+
+
+def sdc_table(coder: FlashCoder, codes: jax.Array) -> jax.Array:
+    """All-pairs :func:`sdc_lookup` among (C, M) codes -> (C, C) f32.
+
+    The element gather ``sdt_q[m, a_m, b_m]`` costs C²·M scalar lookups,
+    which a TPU executes one by one. As one-hot contractions it runs on the
+    MXU: rows[i, m, :] = sdt_q[m, a_im, :], then
+    table[i, j] = Σ_{m,l} rows[i, m, l] · onehot(b_jm)[l]. The levels and
+    their sums are integers below 2^24 and every product has a 0/1 factor,
+    so f32 at HIGHEST precision gives the same integer sums as the gather.
+    """
+    onehot = jax.nn.one_hot(codes, coder.k, dtype=jnp.float32)  # (C, M, K)
+    sdt = coder.sdt_q.astype(jnp.float32)
+    rows = jnp.einsum("cmk,mkl->cml", onehot, sdt, precision=_EXACT)
+    return jnp.einsum("iml,jml->ij", rows, onehot, precision=_EXACT)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,7 @@ def _sq_dists(x: jax.Array, c: jax.Array) -> jax.Array:
     """Squared L2 between rows of x (n,d) and c (k,d) -> (n,k)."""
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(c * c, axis=-1)
-    xc = x @ c.T
+    xc = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(x2 + c2[None, :] - 2.0 * xc, 0.0)
 
 
@@ -58,7 +58,7 @@ def _lloyd_step(x: jax.Array, centroids: jax.Array) -> tuple[jax.Array, jax.Arra
     k = centroids.shape[0]
     one_hot = jax.nn.one_hot(assign, k, dtype=x.dtype)  # (n, k)
     counts = jnp.sum(one_hot, axis=0)  # (k,)
-    sums = one_hot.T @ x  # (k, d)
+    sums = jnp.matmul(one_hot.T, x, precision=jax.lax.Precision.HIGHEST)  # (k, d)
     new = sums / jnp.maximum(counts[:, None], 1.0)
     # Keep old centroid where the cluster went empty, then re-seed it from the
     # farthest point.

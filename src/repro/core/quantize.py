@@ -85,12 +85,17 @@ class TableQuant(NamedTuple):
 def fit_table_quant(
     per_subspace_min: jax.Array, per_subspace_max: jax.Array, *, h: int = 8
 ) -> TableQuant:
-    """Paper §3.3.3: dist_max = Σ_i dist_max_i, dist_min = min_i dist_min_i.
+    """Paper §3.3.3's shared scale: dist_min = min_i dist_min_i and
+    dist_max = max_i dist_max_i — every subspace's partials fit the range.
 
-    The max is summed over subspaces so that the *sum* of quantized partials
-    can never overflow the comparison scale; the min is the global floor.
+    The paper sums the per-subspace maxima so that a *sum* of quantized
+    partials cannot overflow the narrow SIMD lanes it accumulates in. The
+    lookups here accumulate in int32, which no sum of M H-bit levels can
+    overflow, so the range only has to hold one partial: M times finer
+    levels, which near neighbours of a large collection need to be told
+    apart (DESIGN.md §2, A3).
     """
-    dist_max = jnp.sum(per_subspace_max)
+    dist_max = jnp.max(per_subspace_max)
     dist_min = jnp.min(per_subspace_min)
     delta = jnp.maximum(dist_max - dist_min, 1e-12)
     return TableQuant(dist_min=dist_min, delta=delta, h=jnp.asarray(h, jnp.int32))

@@ -24,6 +24,11 @@ L2, or a monotone affine image of it; never mixed across backends):
                                   Pallas kernel (kernels.ops.flash_scan_batch)
                                   instead of W·R random gathers.
     pair_dists(ids_a, ids_b)    -> f32    distances between stored ids
+    pair_table(ids)             -> f32    (C, C) all-pairs distances among
+                                  stored ids (neighbour selection's
+                                  occlusion table). Default: the broadcast
+                                  ``pair_dists``; Flash contracts one-hot
+                                  codes against the SDT on the MXU.
     supports_expand(r)          -> bool   capability hook: can ``expand``
                                   serve adjacency rows of width ``r``?
                                   (static — checked once at trace time by
@@ -51,7 +56,7 @@ L2, or a monotone affine image of it; never mixed across backends):
                                   step in a single kernel — scalar-prefetch
                                   the (W,) frontier, gather adjacency +
                                   packed code rows in-kernel, score via the
-                                  MXU one-hot ADT contraction. Returns the
+                                  packed-nibble ADT lookup. Returns the
                                   gathered (W, R) rows and their (W, R) f32
                                   distances (callers mask invalid slots).
     with_updated_edges(ids, nbr_ids) -> backend   commit hook (blocked layout)
@@ -168,6 +173,10 @@ class _Base:
             f"{type(self).__name__} has no coder-reconstruction path "
             "(recon_vectors); use exact rerank instead"
         )
+
+    def pair_table(self, ids):
+        """(C,) ids -> (C, C) pair distances (selection's occlusion table)."""
+        return self.pair_dists(ids[:, None], ids[None, :])
 
     def neighbor_dists_batch(self, qctx, nodes, ids):  # noqa: ARG002
         # Default: one batched gather-and-score; every backend's query_dists
@@ -434,6 +443,9 @@ class FlashBackend(_Base):
             self.coder, self.codes[ids_a], self.codes[ids_b]
         ).astype(jnp.float32)
 
+    def pair_table(self, ids):
+        return core.sdc_table(self.coder, self.codes[ids])
+
     def round_dists(self, qctxs, ids):
         """One blocked kernel launch per bulk round (DESIGN.md §12): gather
         the candidates' code rows, contract against the per-vertex ADTs.
@@ -479,7 +491,7 @@ class FlashBlockedBackend(FlashBackend):
     This backend owns the fused ``expand()`` path (DESIGN.md §10): one
     Pallas program per beam-expansion step, with the adjacency-row and
     code-row gathers done in-kernel via scalar prefetch and the ADT lookup
-    run as an MXU one-hot contraction (`kernels.ops.flash_expand`).
+    fused behind them (`kernels.ops.flash_expand`).
     """
 
     _fields = ("coder", "codes", "nbr_codes", "raw")
@@ -512,9 +524,9 @@ class FlashBlockedBackend(FlashBackend):
 
     def expand(self, qctx, nodes, adjacency):
         """One fused beam-expansion step: in-kernel gather of the W frontier
-        vertices' adjacency + packed code rows, MXU one-hot ADT contraction
+        vertices' adjacency + packed code rows, packed ADT lookup
         (kernels.ops.flash_expand). Bit-exact with the gather+scan fallback:
-        integer one-hot matmul == integer table gather-sum."""
+        integer table sums are exact in any order."""
         rows, sums = ops.flash_expand(
             nodes, adjacency, self.nbr_codes, qctx.adt_q
         )
@@ -535,22 +547,21 @@ class FlashBlockedBackend(FlashBackend):
         rows = self._mirror_rows_unpacked(nodes)  # (W, R, M)
         return ops.flash_scan_batch(rows, qctx.adt_q).astype(jnp.float32)
 
-    def _pack_rows(self, rows):
-        """Codeword rows (…, R, M) int32 -> the mirror's storage layout."""
-        return core.pack_codes(rows) if self.mirror_packed else rows
-
     def with_updated_edges(self, ids, nbr_ids):
         """ids (...,) vertices whose lists changed (out-of-bounds = dropped);
         nbr_ids (..., R) their new neighbor lists."""
         if nbr_ids.shape[-1] != self.nbr_codes.shape[1]:
             return self  # non-base-layer commit: mirror not affected
-        safe = jnp.maximum(nbr_ids, 0)
-        rows = jnp.where(
-            (nbr_ids >= 0)[..., None], self.codes[safe], 0
-        )  # (..., R, M)
-        nbr_codes = self.nbr_codes.at[ids].set(
-            self._pack_rows(rows), mode="drop"
+        # pack each vertex's codes once, then gather bytes: gathering int32
+        # code rows first would build an (..., R, M) int32 block — 24 GB
+        # in TPU tiling for a whole-layer commit at n = 10⁶
+        table = (
+            core.pack_codes(self.codes) if self.mirror_packed else self.codes
         )
+        rows = jnp.where(
+            (nbr_ids >= 0)[..., None], table[jnp.maximum(nbr_ids, 0)], 0
+        )  # (..., R, ⌈M/2⌉) | (..., R, M)
+        nbr_codes = self.nbr_codes.at[ids].set(rows, mode="drop")
         return FlashBlockedBackend(self.coder, self.codes, nbr_codes, self.raw)
 
     def extend(self, new_vectors):

@@ -182,8 +182,8 @@ def beam_search(
         nodes = jnp.where(sel_ok, beam_ids[bi], -1)  # (W,)
         if use_fused:
             # One fused kernel: in-kernel adjacency + packed-code-row gather
-            # (scalar-prefetched frontier ids) and MXU one-hot ADT
-            # contraction — the per-iteration HBM round trip for the
+            # (scalar-prefetched frontier ids) and the packed ADT
+            # lookup — the per-iteration HBM round trip for the
             # (W·R, M) code block disappears (DESIGN.md §10).
             rows, d_block = backend.expand(qctx, nodes, adjacency)  # (W, R) ×2
         else:

@@ -600,6 +600,11 @@ _BULK_CHUNK = 256
 #: round or two of convergence for a ~3× smaller scoring block per round.
 _BULK_EXPAND = 8
 
+#: rows per vmapped block of a whole-layer selection or prune: each row
+#: holds a (C, C) pair table, so a whole layer at once is n·C² entries —
+#: 9.2·10⁹ at n = 10⁶, past what the TPU compiler can even index.
+_COMMIT_ROWS = 256
+
 #: random extra candidates appended to each final pool before selection —
 #: MRNG keeps the un-occluded ones, which is where the graph gets its
 #: long-range (cross-cluster) edges; pure refined pools converge to local
@@ -609,30 +614,32 @@ _BULK_EXPAND = 8
 _BULK_RANDOM = 32
 
 
-def _bulk_score(backend, qctxs, members, cand, chunk: int):
+def _bulk_score(backend, data, members, cand, chunk: int):
     """Chunked ``round_dists`` scoring of a (m, C) candidate block.
 
-    Scores against precomputed per-member query contexts and masks
-    self/invalid entries to +inf. ``m`` must be a multiple of ``chunk``
-    (the caller pads once). Returns (dists (m, C), bad (m, C) mask).
+    Each chunk's query contexts are built inside the chunk: for a whole
+    layer at once they would be (m, M, K) tables, GBs at n = 10⁶ with a
+    wide coder. Masks self/invalid entries to +inf. ``m`` must be a
+    multiple of ``chunk`` (the caller pads once). Returns (dists (m, C),
+    bad (m, C) mask).
     """
     m, c = cand.shape
     n_chunks = m // chunk
 
     def score(args):
-        qctx, cd = args  # pytree (chunk, …), (chunk, C)
+        mem, cd = args  # (chunk,), (chunk, C)
+        qctx = jax.vmap(backend.prepare_query)(data[mem])
         return backend.round_dists(qctx, jnp.maximum(cd, 0))
 
-    qc = jax.tree.map(lambda a: a.reshape(n_chunks, chunk, *a.shape[1:]), qctxs)
     d = jax.lax.map(
-        score, (qc, cand.reshape(n_chunks, chunk, c))
+        score, (members.reshape(n_chunks, chunk), cand.reshape(n_chunks, chunk, c))
     ).reshape(m, c)
 
     bad = (cand < 0) | (cand == members[:, None])
     return jnp.where(bad, INF, d), bad
 
 
-def _bulk_score_topk(backend, qctxs, members, cand, pool_p: int, chunk: int):
+def _bulk_score_topk(backend, data, members, cand, pool_p: int, chunk: int):
     """Score a (m, C) candidate block and keep the best P per row — NO
     dedup. A repeated id occupies repeated pool slots for a round, which
     wastes a little pool width but skips the per-row id-sort (the single
@@ -641,7 +648,7 @@ def _bulk_score_topk(backend, qctxs, members, cand, pool_p: int, chunk: int):
     never see duplicates. Returns (ids, dists, n_scored) like the merge.
     """
     m, c = cand.shape
-    d, bad = _bulk_score(backend, qctxs, members, cand, chunk)
+    d, bad = _bulk_score(backend, data, members, cand, chunk)
     neg, idx = jax.lax.top_k(-d, pool_p)
     new_d = -neg
     new_ids = jnp.take_along_axis(cand, idx, axis=1)
@@ -653,7 +660,7 @@ def _bulk_score_topk(backend, qctxs, members, cand, pool_p: int, chunk: int):
     )
 
 
-def _bulk_score_merge(backend, qctxs, members, cand, pool_p: int, chunk: int):
+def _bulk_score_merge(backend, data, members, cand, pool_p: int, chunk: int):
     """Score a (m, C) candidate block and merge to the best P per row.
 
     Traced helper shared by pool init and the loop-exit cleanup: chunked
@@ -662,7 +669,7 @@ def _bulk_score_merge(backend, qctxs, members, cand, pool_p: int, chunk: int):
     by distance −1-padded, dists (m, P) +inf-padded, n_scored).
     """
     m, c = cand.shape
-    d, bad = _bulk_score(backend, qctxs, members, cand, chunk)
+    d, bad = _bulk_score(backend, data, members, cand, chunk)
     n_scored = jnp.sum(~bad)
     # Dedup: stable-sort each row by id (invalids to a sentinel past any
     # real id), strike adjacent repeats; merging then works directly on the
@@ -708,9 +715,8 @@ def _bulk_refine_jit(
     member row. Returns (pool_ids (m_pad, P+S), pool_d, n_rounds,
     n_scored).
     """
-    qctxs = jax.vmap(backend.prepare_query)(data[members])
     pool_ids, pool_d, nsc0 = _bulk_score_merge(
-        backend, qctxs, members, cand0, pool_p, chunk
+        backend, data, members, cand0, pool_p, chunk
     )
 
     def cond(carry):
@@ -722,11 +728,11 @@ def _bulk_refine_jit(
         m = pool_ids.shape[0]
         top = pool_ids[:, :r_exp]  # (m, E) global ids
         ok = top >= 0
-        rows = pool_ids[inv[jnp.maximum(top, 0)]][:, :, :r_exp]  # (m, E, E)
+        rows = top[inv[jnp.maximum(top, 0)]]  # (m, E, E): E-prefix rows
         non = jnp.where(ok[:, :, None], rows, -1).reshape(m, r_exp * r_exp)
         cand = jnp.concatenate([pool_ids, non], axis=1)  # (m, P + E²)
         new_ids, new_d, nsc = _bulk_score_topk(
-            backend, qctxs, members, cand, pool_p, chunk
+            backend, data, members, cand, pool_p, chunk
         )
         changed = jnp.sum(jnp.any(new_ids != pool_ids, axis=1) & valid)
         return new_ids, new_d, rounds + 1, changed, n_scored + nsc
@@ -739,7 +745,7 @@ def _bulk_refine_jit(
     # of the pool against itself strikes the accumulated repeats before
     # anything downstream consumes it.
     pool_ids, pool_d, nsc_c = _bulk_score_merge(
-        backend, qctxs, members, pool_ids, pool_p, chunk
+        backend, data, members, pool_ids, pool_p, chunk
     )
     n_scored = n_scored + nsc_c
     # Random augmentation: append S scored random members to each pool so
@@ -748,7 +754,7 @@ def _bulk_refine_jit(
     # any duplicate of a pool entry (pair distance 0), so the tail only
     # has to be scored. The refined NN prefix stays intact (NSG's knn
     # slice is safe).
-    aug_d, aug_bad = _bulk_score(backend, qctxs, members, rnd_aug, chunk)
+    aug_d, aug_bad = _bulk_score(backend, data, members, rnd_aug, chunk)
     pool_ids = jnp.concatenate(
         [pool_ids, jnp.where(aug_bad, -1, rnd_aug)], axis=1
     )
@@ -888,12 +894,13 @@ def bulk_reverse(adj, adj_d, backend, members, sel_ids, sel_d,
     ids_s = jnp.where(dup, -1, ids_s)
     d_s = jnp.where(dup, INF, d_s)
 
-    pruned = jax.vmap(
-        lambda ci, cd: prune_list(
-            backend, ci, cd, r=r,
+    pruned = jax.lax.map(
+        lambda a: prune_list(
+            backend, *a, r=r,
             alpha=params.bulk_select_alpha(), mode=params.prune_mode,
-        )
-    )(ids_s, d_s)
+        ),
+        (ids_s, d_s), batch_size=_COMMIT_ROWS,
+    )
     new_adj = jnp.where(touched[:, None], pruned.ids, adj)
     new_adj_d = jnp.where(touched[:, None], pruned.dists, adj_d)
     backend = backend.with_updated_edges(
@@ -920,11 +927,12 @@ def bulk_commit(engine: BuildEngine, adj, adj_d, backend, members,
     pool_ids = jnp.take_along_axis(pool_ids, order, axis=1)
     pool_d = jnp.take_along_axis(pool_d, order, axis=1)
     if p.select_mode == "heuristic":
-        sel = jax.vmap(
-            lambda ci, cd: select_neighbors(
-                backend, ci, cd, r=r, alpha=p.bulk_select_alpha()
-            )
-        )(pool_ids, pool_d)
+        sel = jax.lax.map(
+            lambda a: select_neighbors(
+                backend, *a, r=r, alpha=p.bulk_select_alpha()
+            ),
+            (pool_ids, pool_d), batch_size=_COMMIT_ROWS,
+        )
     else:
         sel = engine.select(backend, pool_ids, pool_d, r=r)
     mask = jnp.ones(members.shape, bool)

@@ -30,14 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5: top-level export, replication check renamed to check_vma
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 from repro import core
 from repro.graph import backends as bk
 from repro.kernels import ops
@@ -103,13 +95,22 @@ def build_segments_vmapped(
     *,
     params: HNSWParams,
 ) -> SegmentedIndexes:
-    """Reference/local form: vmap over the segment axis (S, n_s, D).
+    """Reference/local form: the shard_map deployment's per-device program
+    run on one device for each segment in turn, stacked on the segment axis.
 
-    Semantically identical to the shard_map deployment (same per-segment
-    program); used by tests and by single-host benchmarks.
+    One segment per call, as on each device of the mesh: a vmap over all S
+    segments at once batches the float matmuls differently, which can move
+    a quantized table entry across a level boundary and change the graph.
+    Used by tests and by single-host benchmarks.
     """
-    f = functools.partial(build_segment, params=params)
-    index = jax.vmap(f, in_axes=(0, None, 0, 0))(data_segs, coder, levels, entries)
+    f = jax.jit(jax.vmap(
+        functools.partial(build_segment, params=params), in_axes=(0, None, 0, 0)
+    ))
+    parts = [
+        f(data_segs[s : s + 1], coder, levels[s : s + 1], entries[s : s + 1])
+        for s in range(data_segs.shape[0])
+    ]
+    index = jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts)
     return SegmentedIndexes(index=index)
 
 
@@ -129,12 +130,12 @@ def make_segmented_build_fn(mesh, *, params: HNSWParams, seg_axes=("pod", "data"
         return jax.vmap(f, in_axes=(0, None, 0, 0))(data_seg, coder, levels, entries)
 
     def build(data_segs, coder, levels, entries):
-        return _shard_map(
+        return jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(spec_seg, P(), spec_seg, spec_seg),
             out_specs=spec_seg,
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )(data_segs, coder, levels, entries)
 
     return build
@@ -200,12 +201,12 @@ def make_segmented_search_fn(
         return out_ids, -neg
 
     def search(index_stack, queries, id_offsets, seg_vectors):
-        return _shard_map(
+        return jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(spec_seg, P(), spec_seg, spec_seg),
             out_specs=(P(), P()),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )(index_stack, queries, id_offsets, seg_vectors)
 
     return search

@@ -7,8 +7,8 @@ slack α ≥ 1 (exclude iff α·δ(u, v) < δ(v, x)); α = 1 is exactly HNSW.
 
 The scan is sequential in the candidate order but each step is vectorized:
 we precompute the (C, C) candidate pair-distance matrix through the backend
-(for Flash these are SDT lookups — the cache/VMEM-resident table of §3.3.3,
-*zero* vector fetches) and run a ``lax.scan`` of C O(C) steps.
+(``pair_table``; for Flash SDT sums — the table of §3.3.3, *zero* vector
+fetches, contracted on the MXU) and run a ``lax.scan`` of C O(C) steps.
 
 The same routine prunes overflowing reverse-edge lists (line 7): candidates
 are then "existing neighbors ∪ {new vertex}".
@@ -48,7 +48,7 @@ def select_neighbors(
     valid = cand_ids >= 0
     safe = jnp.where(valid, cand_ids, 0)
     # (C, C) pair distances via the backend (Flash: SDT lookups).
-    pair = backend.pair_dists(safe[:, None], safe[None, :])
+    pair = backend.pair_table(safe)
     pair = jnp.where(valid[:, None] & valid[None, :], pair, INF)
 
     def step(carry, i):

@@ -475,9 +475,10 @@ class ShardedBuilder:
     Mode resolution (``build``): an explicit ``mesh=`` (or the ambient
     ``distributed.context`` mesh) with more than one device runs the
     stacked ``shard_map`` program; otherwise ``workers > 1`` runs a spawn
-    process pool of single-device workers; otherwise everything runs
-    inline — same assignment, same per-segment program, one process (the
-    graceful single-device fallback)."""
+    process pool of single-device CPU workers (refused on TPU, where a
+    child cannot reach the chip its parent holds); otherwise everything
+    runs inline — same assignment, same per-segment program, one process
+    (the graceful single-device fallback)."""
 
     def __init__(self, config: ShardConfig, *, workers: int | None = None,
                  mesh=None, workdir: str | None = None):
@@ -576,6 +577,15 @@ class ShardedBuilder:
         if dctx.device_count(mesh) > 1:
             return "mesh", mesh
         if self.workers is not None and self.workers > 1:
+            if jax.default_backend() == "tpu":
+                # every spawned worker would import JAX and need the chip
+                # this process already holds: one process per chip
+                raise RuntimeError(
+                    "ShardedBuilder(workers>1) spawns processes that each "
+                    "need an accelerator, and this process already holds "
+                    "it; on TPU pass mesh= (one segment per device) or "
+                    "leave workers unset to build inline"
+                )
             return "pool", None
         return "inline", None
 

@@ -3,17 +3,18 @@
 Kernels (each <name>.py has a pl.pallas_call + explicit BlockSpec VMEM tiling;
 ref.py holds the pure-jnp oracle; ops.py the jit'd dispatching wrappers):
 
-  flash_scan   — batched ADT lookup-accumulate (the CPU `pshufb` analogue,
-                 paper §3.3.5), flat and access-aware-blocked (§3.3.4) forms.
+  flash_scan   — ADT lookup-accumulate (the CPU `pshufb` analogue, paper
+                 §3.3.5) over the blocked code layout (§3.3.4); the flat
+                 scan and the bulk refinement-round scan (DESIGN.md §12,
+                 one table per row) are the same kernel.
   flash_expand — one fused beam-expansion step (DESIGN.md §10): scalar-
                  prefetched in-kernel gather of adjacency + packed 4-bit
-                 code rows, MXU one-hot ADT contraction.
-  flash_round  — bulk refinement-round scan (DESIGN.md §12): one RNN-
-                 Descent round's (B, C) candidate block scored against
-                 per-vertex ADTs (the batched-table flash_scan).
+                 code tiles, nibble lookup against the even/odd ADT rows.
   l2_batch     — tiled ‖x‖²+‖y‖²−2x·yᵀ distance matrix on the MXU
                  (full-precision baseline path + k-means training).
   sq_l2        — int-domain scaled L2 for the optimized HNSW-SQ baseline.
+
+Every ``*_pallas`` entry point takes a required ``interpret`` flag.
 """
 
 from repro.kernels import ops, ref  # noqa: F401

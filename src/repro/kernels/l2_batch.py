@@ -46,7 +46,7 @@ def l2_batch_pallas(
     *,
     block_n: int = 256,
     block_c: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """x (N, D), y (C, D) -> (N, C) float32 squared distances."""
     n, d = x.shape

@@ -24,8 +24,11 @@ import jax.numpy as jnp
 from repro import obs
 from repro.kernels import ref
 from repro.kernels.flash_expand import flash_expand_pallas
-from repro.kernels.flash_round import flash_round_pallas
-from repro.kernels.flash_scan import flash_scan_blocked_pallas, flash_scan_pallas
+from repro.kernels.flash_scan import (
+    flash_round_pallas,
+    flash_scan_blocked_pallas,
+    flash_scan_pallas,
+)
 from repro.kernels.l2_batch import l2_batch_pallas
 from repro.kernels.sq_l2 import sq_l2_pallas
 
@@ -142,8 +145,8 @@ def flash_expand(
     nodes (W,), adjacency (n, R), mirror (n, R, ⌈M/2⌉) packed uint8 (or
     (n, R, M) int32 legacy), adt (M, K) -> (rows (W, R), sums (W, R)).
     One program per frontier vertex: scalar-prefetched in-kernel gather of
-    the adjacency row and packed code row, fused unpack, MXU one-hot ADT
-    contraction. The ``backend.expand()`` capability hook routes here.
+    the adjacency row and packed code row, nibble lookup against the even
+    and odd ADT rows. The ``backend.expand()`` capability hook routes here.
     """
     impl = resolve_impl(impl)
     _trace_tick("flash_expand", impl)
@@ -215,7 +218,7 @@ def sq_l2(
     s2: jax.Array,
     *,
     impl: str = "auto",
-    block_n: int = 512,
+    block_n: int = 1024,
 ) -> jax.Array:
     """SQ quantized-domain distance: q (D,), db (N, D), s2 (D,) -> (N,) f32."""
     impl = resolve_impl(impl)

@@ -10,7 +10,9 @@ is a single fused multiply before the lane reduction.
 
 Tiling: grid over ⌈N/block_n⌉ database rows; the query codes and the scale
 vector are replicated into every tile (tiny: D ≤ 4096 ⇒ ≤ 32 KiB together).
-Database tile (block_n=512, D=1024, int32): 2 MiB « VMEM ✓.
+``block_n`` is 1024 because XLA tiles a long 1-D f32 array in 1024-element
+tiles on TPU and the (N,) output block must match that tiling.
+Database tile (block_n=1024, D=768, int32): 3 MiB « VMEM ✓.
 """
 
 from __future__ import annotations
@@ -36,8 +38,8 @@ def sq_l2_pallas(
     db: jax.Array,
     s2: jax.Array,
     *,
-    block_n: int = 512,
-    interpret: bool = True,
+    block_n: int = 1024,
+    interpret: bool,
 ) -> jax.Array:
     """q (D,) int codes, db (N, D) int codes, s2 (D,) f32 -> (N,) f32."""
     n, d = db.shape

@@ -14,19 +14,29 @@ Topology (TPU v5e-class):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the programs here place data with
+    ``shard_map`` specs and index stacked outputs on the host, which
+    Explicit axes (``make_mesh``'s default) would have to be annotated for."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU smoke)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_segment_mesh(n: int | None = None):
@@ -42,7 +52,7 @@ def make_segment_mesh(n: int | None = None):
         n = len(devs)
     if not 1 <= n <= len(devs):
         raise ValueError(f"asked for {n} devices, have {len(devs)}")
-    return jax.make_mesh((n,), ("data",), devices=devs[:n])
+    return _mesh((n,), ("data",), devices=devs[:n])
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import os
 import time
 from contextlib import contextmanager
 from typing import Any, Iterator
@@ -54,6 +55,23 @@ def pad_to(x: jax.Array, size: int, axis: int = 0, value=0) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, size - cur)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def use_compile_cache(checkout: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry-point script.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the only setting: JAX reads
+    it itself. Otherwise the cache lives at ``<checkout>/.jax_cache`` — a
+    fixed path, because the path is part of what a later run must find
+    again. Scripts call this; the library and the tests never do.
+    Returns the cache directory.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @contextmanager

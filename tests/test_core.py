@@ -254,6 +254,21 @@ class TestFlashCoder:
         self_d = core.sdc_lookup(coder, codes, codes)
         assert int(jnp.max(self_d)) <= coder.m_f  # ≤ 1 rounding level per subspace
 
+    @pytest.mark.parametrize("top", [255, 2**16 - 1])
+    def test_sdc_table_bit_exact_with_lookup(self, coder, small_data, key, top):
+        """The one-hot MXU form gives the gather's integer sums exactly,
+        also for levels wider than bf16's 8-bit mantissa."""
+        data, _ = small_data
+        sdt = jax.random.randint(key, coder.sdt_q.shape, 0, top + 1)
+        wide = coder._replace(sdt_q=sdt)
+        codes = core.encode(coder, data[:96])
+        want = core.sdc_lookup(wide, codes[:, None], codes[None, :])
+        got = core.sdc_table(wide, codes)
+        assert got.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want).astype(np.float32)
+        )
+
     def test_adc_ordering_tracks_true_ordering(self, coder, small_data):
         data, _ = small_data
         q = data[0]

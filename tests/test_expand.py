@@ -123,7 +123,7 @@ class TestFlashExpandKernel:
         adt = jnp.zeros((16, 16), jnp.int32)
         bad = jnp.zeros((10, 4, 5), jnp.uint8)  # expect ceil(16/2) = 8
         with pytest.raises(ValueError, match="mirror"):
-            flash_expand_pallas(nodes, adj, bad, adt)
+            flash_expand_pallas(nodes, adj, bad, adt, interpret=True)
 
 
 # ---------------------------------------------------------------------------

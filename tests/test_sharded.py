@@ -406,6 +406,18 @@ class TestFallback:
         assert res.mode == "inline"
         assert res.index.n == 400
 
+    def test_pool_refused_on_tpu(self, data, tmp_path, monkeypatch):
+        """A spawned worker cannot reach the chip its parent holds: on a
+        TPU backend ``workers>1`` raises before any process starts."""
+        import repro.graph.sharded as sharded_mod
+
+        monkeypatch.setattr(sharded_mod.jax, "default_backend", lambda: "tpu")
+        builder = ShardedBuilder(
+            _config(tmp_path, n_segments=2), workers=2, workdir=str(tmp_path)
+        )
+        with pytest.raises(RuntimeError, match="mesh="):
+            builder.build(data[:400])
+
     def test_build_streaming_facade(self, data, queries, tmp_path):
         idx = SegmentedAnnIndex.build_streaming(
             data, n_segments=S, chunk_size=256, algo="hnsw", backend="fp32",
