@@ -1,0 +1,11 @@
+"""Device milliseconds of the bulk commit programs (MRNG selection, forward and reverse edges) in one traced
+build, found by their stable program name."""
+
+from harness import trace
+
+PROGRAM = "bulk_commit"
+
+
+def read(r):
+    s = trace.module_seconds(r.record, PROGRAM)
+    return None if s is None else 1e3 * s
