@@ -1,0 +1,12 @@
+"""Device idle milliseconds of one traced build while the host was inside
+``build/bulk_refine`` or ``build/bulk_commit``: the graph's refinement and
+commit.
+
+Read from the program's spans (``harness/spans.py``); None where the
+program has none on the profiler's clock."""
+
+from harness import spans
+
+
+def read(r):
+    return spans.idle_ms(r.record, "graph")

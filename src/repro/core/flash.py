@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import kmeans as km
 from repro.core import pca as pca_mod
 from repro.core import quantize as qz
@@ -134,42 +135,45 @@ def fit_flash(
     k = 1 << l_f
     ds = -(-d_f // m_f)  # ceil
 
-    model = pca_mod.fit_pca(sample, max_sample=max_fit_sample)
-    # Balance variance across subspaces: principal dims are assigned
-    # round-robin (subspace m gets dims m, m+M, m+2M, …). With contiguous
-    # chunks the first subspace would dominate the shared (dist_min, Δ)
-    # quantization range (Eq. 9) and starve the rest of the 2^H levels —
-    # this is the "bit utilization" co-design of §3.3.2/§3.3.3. The
-    # permutation (and zero-padding of d_F up to M·ds) is folded into the
-    # rotation, so encode/query pay no runtime cost.
-    d_pad = m_f * ds
-    rot_np = np.zeros((d_in, d_pad), np.float32)
-    rot_np[:, :d_f] = np.asarray(model.components[:, :d_f])
-    perm = np.concatenate([np.arange(m, d_pad, m_f) for m in range(m_f)])
-    rot = jnp.asarray(rot_np[:, perm])
-    mean = model.mean
+    with obs.span("build/coder/pca", d_in=d_in):
+        # the host copy, the float64 covariance and its eigh
+        model = pca_mod.fit_pca(sample, max_sample=max_fit_sample)
+        # Balance variance across subspaces: principal dims are assigned
+        # round-robin (subspace m gets dims m, m+M, m+2M, …). With contiguous
+        # chunks the first subspace would dominate the shared (dist_min, Δ)
+        # quantization range (Eq. 9) and starve the rest of the 2^H levels —
+        # this is the "bit utilization" co-design of §3.3.2/§3.3.3. The
+        # permutation (and zero-padding of d_F up to M·ds) is folded into the
+        # rotation, so encode/query pay no runtime cost.
+        d_pad = m_f * ds
+        rot_np = np.zeros((d_in, d_pad), np.float32)
+        rot_np[:, :d_f] = np.asarray(model.components[:, :d_f])
+        perm = np.concatenate([np.arange(m, d_pad, m_f) for m in range(m_f)])
+        rot = jnp.asarray(rot_np[:, perm])
+        mean = model.mean
 
-    fit_rows = min(n, max_fit_sample)
-    z = jnp.matmul(sample[:fit_rows] - mean, rot, precision=_EXACT)  # (n', d_F)
-    subs = _split_subspaces(z, m_f, ds)  # (M, n', ds)
+    with obs.span("build/coder/kmeans", k=k, iters=kmeans_iters):
+        fit_rows = min(n, max_fit_sample)
+        z = jnp.matmul(sample[:fit_rows] - mean, rot, precision=_EXACT)  # (n', d_F)
+        subs = _split_subspaces(z, m_f, ds)  # (M, n', ds)
 
-    codebooks, _ = km.kmeans_fit_batched(key, subs, k=k, iters=kmeans_iters)
+        codebooks, _ = km.kmeans_fit_batched(key, subs, k=k, iters=kmeans_iters)
 
-    # Symmetric tables: inter-centroid squared partial distances.
-    diff = codebooks[:, :, None, :] - codebooks[:, None, :, :]  # (M, K, K, ds)
-    sdt_f = jnp.sum(diff * diff, axis=-1)  # (M, K, K)
+        # Symmetric tables: inter-centroid squared partial distances.
+        diff = codebooks[:, :, None, :] - codebooks[:, None, :, :]  # (M, K, K, ds)
+        sdt_f = jnp.sum(diff * diff, axis=-1)  # (M, K, K)
 
-    # Shared quantizer calibration (§3.3.3): per-subspace [min,max] over both
-    # sample-to-centroid (ADT-like) and centroid-to-centroid (SDT) partials.
-    d_sample = _partial_dists(subs, codebooks)  # (M, n', K)
-    per_min = jnp.minimum(
-        jnp.min(d_sample, axis=(1, 2)), jnp.min(sdt_f, axis=(1, 2))
-    )
-    per_max = jnp.maximum(
-        jnp.max(d_sample, axis=(1, 2)), jnp.max(sdt_f, axis=(1, 2))
-    )
-    tq = qz.fit_table_quant(per_min, per_max, h=h)
-    sdt_q = qz.quantize_table(tq, sdt_f)
+        # Shared quantizer calibration (§3.3.3): per-subspace [min,max] over both
+        # sample-to-centroid (ADT-like) and centroid-to-centroid (SDT) partials.
+        d_sample = _partial_dists(subs, codebooks)  # (M, n', K)
+        per_min = jnp.minimum(
+            jnp.min(d_sample, axis=(1, 2)), jnp.min(sdt_f, axis=(1, 2))
+        )
+        per_max = jnp.maximum(
+            jnp.max(d_sample, axis=(1, 2)), jnp.max(sdt_f, axis=(1, 2))
+        )
+        tq = qz.fit_table_quant(per_min, per_max, h=h)
+        sdt_q = qz.quantize_table(tq, sdt_f)
 
     return FlashCoder(
         mean=mean,

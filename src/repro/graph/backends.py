@@ -98,7 +98,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import core
+from repro import core, obs
 from repro.kernels import ops
 
 
@@ -660,20 +660,24 @@ def make_backend(
         return PQBackend(coder, core.pq_encode(coder, data), raw)
     if kind in ("flash", "flash_blocked"):
         coder = core.fit_flash(key, data, **coder_kwargs)
-        codes = core.encode(coder, data)
-        if kind == "flash":
-            return FlashBackend(coder, codes, raw)
-        if r_for_blocked is None:
-            raise ValueError("flash_blocked needs r_for_blocked (max neighbors)")
-        if coder.k <= 16:  # 4-bit codes: packed mirror (two per byte)
-            nbr_codes = jnp.zeros(
-                (data.shape[0], r_for_blocked, (coder.m_f + 1) // 2), jnp.uint8
-            )
-        else:  # K > 16 (PQ-style tables): unpacked legacy layout
-            nbr_codes = jnp.zeros(
-                (data.shape[0], r_for_blocked, coder.m_f), jnp.int32
-            )
-        return FlashBlockedBackend(coder, codes, nbr_codes, raw)
+        with obs.span("build/coder/encode"):
+            codes = core.encode(coder, data)
+            if kind == "flash":
+                return FlashBackend(coder, codes, raw)
+            if r_for_blocked is None:
+                raise ValueError(
+                    "flash_blocked needs r_for_blocked (max neighbors)"
+                )
+            if coder.k <= 16:  # 4-bit codes: packed mirror (two per byte)
+                nbr_codes = jnp.zeros(
+                    (data.shape[0], r_for_blocked, (coder.m_f + 1) // 2),
+                    jnp.uint8,
+                )
+            else:  # K > 16 (PQ-style tables): unpacked legacy layout
+                nbr_codes = jnp.zeros(
+                    (data.shape[0], r_for_blocked, coder.m_f), jnp.int32
+                )
+            return FlashBlockedBackend(coder, codes, nbr_codes, raw)
     raise ValueError(
         f"unknown backend kind {kind!r}; valid kinds: {', '.join(KINDS)}"
     )

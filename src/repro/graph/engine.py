@@ -821,14 +821,7 @@ def bulk_refine(
         max_rounds=params.bulk_rounds,
     )
     rounds = int(rounds)
-    if obs.enabled():
-        # init merge + per-round passes + exit merge + random augmentation,
-        # each chunked into m_pad // chunk round_dists launches.
-        obs.tick(
-            "bulk_round_batches_total",
-            n=(rounds + 3) * (m_pad // chunk), layer=str(layer),
-        )
-        obs.tick("bulk_rounds_total", n=rounds, layer=str(layer))
+    obs.tick("bulk_rounds_total", n=rounds, layer=str(layer))
     return (
         pool_ids[:m], pool_d[:m],
         float(n_scored), float(m * r_exp * rounds), rounds,
@@ -979,112 +972,160 @@ def repair_reachability(
     ``max_passes``. Any pathological leftovers (every reverse edge pruned)
     are force-linked to their nearest reachable vertex.
 
+    Runs in a ``build/repair`` span (attribute ``passes``) whose children
+    are each host BFS (``build/repair/bfs``, attribute ``unreachable``),
+    each re-insertion pass (``build/repair/reinsert``: ``unreachable``, and
+    the schedule length before and after padding, ``schedule`` and
+    ``schedule_padded``) and the forced-link loop (``build/repair/graft``).
+
     Returns (adj0, adj0_d, adj_up, adj_up_d, backend, n_dists, n_hops).
     """
+    with obs.span("build/repair") as sp:
+        out = _repair_reachability(
+            data, adj0, adj0_d, adj_up, adj_up_d, backend, levels, entry,
+            params=params, max_passes=max_passes, sp=sp,
+        )
+        sp.add_cost(out[5], out[6])
+    return out
+
+
+def _bfs_unreachable(adj0, entry: int):
+    """Host BFS of the base layer from ``entry``, with the copy of the
+    adjacency to the host, in a ``build/repair/bfs`` span: (host adjacency,
+    seen mask, unreachable ids)."""
+    with obs.span("build/repair/bfs") as sp:
+        adj_np = np.asarray(adj0)
+        seen = bfs_reachable(adj_np, entry)
+        unreach = np.nonzero(~seen)[0].astype(np.int32)
+        sp.set(unreachable=int(unreach.size))
+    return adj_np, seen, unreach
+
+
+def _repair_reachability(
+    data, adj0, adj0_d, adj_up, adj_up_d, backend, levels, entry: int,
+    *, params: BuildParams, max_passes: int, sp,
+):
     engine = BuildEngine(params)
     n = int(adj0.shape[0])
     n_d = n_h = 0.0
-    for _ in range(max_passes):
-        seen = bfs_reachable(np.asarray(adj0), int(entry))
-        unreach = np.nonzero(~seen)[0].astype(np.int32)
+    sp.set(passes=0)
+    for p in range(max_passes):
+        _, _, unreach = _bfs_unreachable(adj0, int(entry))
         if unreach.size == 0:
             return adj0, adj0_d, adj_up, adj_up_d, backend, n_d, n_h
         if unreach.size > n // 4:
             break  # mostly islands: beams from the tiny reachable core
             # cannot acquire island-local neighbors — go structural
-        ids, mask = batch_schedule(unreach, params.batch)
-        # pad the schedule length to a power of two so repair passes of
-        # similar size share one run_insert_schedule compile
-        nb = ids.shape[0]
-        nb_p = 1 << (nb - 1).bit_length()
-        ids = np.concatenate([ids, np.zeros((nb_p - nb, params.batch), np.int32)])
-        mask = np.concatenate([mask, np.zeros((nb_p - nb, params.batch), bool)])
-        ent = np.full((nb_p,), int(entry), np.int32)
-        adj0, adj0_d, adj_up, adj_up_d, backend, acct = run_insert_schedule(
-            engine, data, adj0, adj0_d, adj_up, adj_up_d, backend,
-            jnp.asarray(levels), jnp.asarray(ids), jnp.asarray(ent),
-            jnp.asarray(mask),
-        )
-        n_d += float(acct.n_dists)
-        n_h += float(acct.n_hops)
-    adj_np = np.asarray(adj0).copy()
-    adj_d_np = np.asarray(adj0_d).copy()
-    seen = bfs_reachable(adj_np, int(entry))
-    if not seen.all():
-        unreach = np.nonzero(~seen)[0].astype(np.int32)
-        all_ids = jnp.arange(n, dtype=jnp.int32)
-        # Batched distance rows (unreachable × everyone), tiled at a fixed
-        # row-block shape: per-u calls would recompile per shape as the
-        # reachable set grows, and one monolithic (U, n) call materializes
-        # an (U, n, ·) workspace in the backend — at mostly-island scale
-        # (U ≈ n) that is O(n²·d) bytes. Fixed blocks compile once and cap
-        # the workspace; padding rows are discarded (values unchanged).
-        u_sz = int(unreach.size)
-        budget = int(os.environ.get("REPRO_REPAIR_TILE", 1 << 19))
-        blk = max(1, min(u_sz, budget // max(1, n)))
-        pad = (-u_sz) % blk
-        u_pad = np.concatenate([unreach, np.zeros(pad, np.int32)])
-        d_all = np.concatenate([
-            np.asarray(backend.pair_dists(
-                jnp.asarray(u_pad[i:i + blk, None]), all_ids[None, :],
-            ))
-            for i in range(0, u_sz + pad, blk)
-        ])[:u_sz]
-        n_d += float(d_all.size)
-        row_of = {int(u): i for i, u in enumerate(unreach)}
-
-        def dists_from(v: int) -> np.ndarray:
-            i = row_of.get(v)
-            if i is not None:
-                return d_all[i]
-            return np.asarray(backend.pair_dists(
-                jnp.full((1, 1), v, jnp.int32), all_ids[None, :],
-            ))[0]
-
-        grafted = np.zeros(adj_np.shape, bool)  # graft slots are permanent
-
-        def link(u: int, y: int, d: float) -> bool:
-            row = adj_np[y]
-            free = np.nonzero(row < 0)[0]
-            if free.size:
-                slot = int(free[0])
-            else:
-                evictable = np.nonzero(~grafted[y])[0]
-                if evictable.size == 0:
-                    return False  # row is all grafts — caller picks another y
-                # evict the smallest-distance edge: its target sits in the
-                # dense local neighborhood with many alternative in-edges
-                slot = int(evictable[np.argmin(adj_d_np[y, evictable])])
-            adj_np[y, slot] = u
-            adj_d_np[y, slot] = d
-            grafted[y, slot] = True
-            return True
-
-        # Per island (forward-closure component): graft the best border
-        # pair (u*, y*) — min distance from any island member to any
-        # reachable vertex — then flood the island's closure as seen.
-        # Grafts never evict each other (no ping-pong), so every pass
-        # makes permanent progress; the outer BFS re-run heals nodes cut
-        # loose when a graft evicted their only in-edge.
-        for _ in range(64):
-            todo = np.nonzero(~seen)[0]
-            if todo.size == 0:
-                break
-            for u in todo:
-                while not seen[u]:
-                    comp = bfs_reachable(adj_np, int(u)) & ~seen
-                    members = np.nonzero(comp)[0]
-                    d_sub = np.stack([dists_from(int(v)) for v in members])
-                    d_sub = np.where(seen[None, :], d_sub, np.inf)
-                    while True:
-                        flat = int(np.argmin(d_sub))
-                        ui, y = divmod(flat, n)
-                        if link(int(members[ui]), y, float(d_sub[ui, y])):
-                            break
-                        d_sub[:, y] = np.inf  # row saturated with grafts
-                    seen |= bfs_reachable(adj_np, int(members[ui]))
-            seen = bfs_reachable(adj_np, int(entry))
-        adj0 = jnp.asarray(adj_np)
-        adj0_d = jnp.asarray(adj_d_np)
-        backend = backend.with_updated_edges(all_ids, adj0)
+        with obs.span(
+            "build/repair/reinsert", unreachable=int(unreach.size)
+        ) as rsp:
+            ids, mask = batch_schedule(unreach, params.batch)
+            # pad the schedule length to a power of two so repair passes of
+            # similar size share one run_insert_schedule compile
+            nb = ids.shape[0]
+            nb_p = 1 << (nb - 1).bit_length()
+            rsp.set(schedule=nb, schedule_padded=nb_p)
+            pad = np.zeros((nb_p - nb, params.batch), np.int32)
+            ids = np.concatenate([ids, pad])
+            mask = np.concatenate([mask, pad.astype(bool)])
+            ent = np.full((nb_p,), int(entry), np.int32)
+            adj0, adj0_d, adj_up, adj_up_d, backend, acct = run_insert_schedule(
+                engine, data, adj0, adj0_d, adj_up, adj_up_d, backend,
+                jnp.asarray(levels), jnp.asarray(ids), jnp.asarray(ent),
+                jnp.asarray(mask),
+            )
+            n_d += float(acct.n_dists)
+            n_h += float(acct.n_hops)
+        sp.set(passes=p + 1)
+    adj_np, seen, unreach = _bfs_unreachable(adj0, int(entry))
+    if unreach.size:
+        with obs.span("build/repair/graft", unreachable=int(unreach.size)):
+            adj0, adj0_d, backend, n_d = _graft(
+                adj_np.copy(), np.array(adj0_d), backend, seen, unreach,
+                int(entry), n_d,
+            )
     return adj0, adj0_d, adj_up, adj_up_d, backend, n_d, n_h
+
+
+def _graft(adj_np, adj_d_np, backend, seen, unreach, entry: int, n_d):
+    """Force-link every vertex in ``unreach`` to its nearest vertex already
+    ``seen``, editing the host copies ``adj_np``/``adj_d_np`` of the base
+    layer. Returns (adj0, adj0_d, backend, n_d), ``n_d`` grown by the
+    distances computed."""
+    n = adj_np.shape[0]
+    all_ids = jnp.arange(n, dtype=jnp.int32)
+    # Batched distance rows (unreachable × everyone), tiled at a fixed
+    # row-block shape: per-u calls would recompile per shape as the
+    # reachable set grows, and one monolithic (U, n) call materializes
+    # an (U, n, ·) workspace in the backend — at mostly-island scale
+    # (U ≈ n) that is O(n²·d) bytes. Fixed blocks compile once and cap
+    # the workspace; padding rows are discarded (values unchanged).
+    u_sz = int(unreach.size)
+    budget = int(os.environ.get("REPRO_REPAIR_TILE", 1 << 19))
+    blk = max(1, min(u_sz, budget // max(1, n)))
+    pad = (-u_sz) % blk
+    u_pad = np.concatenate([unreach, np.zeros(pad, np.int32)])
+    d_all = np.concatenate([
+        np.asarray(backend.pair_dists(
+            jnp.asarray(u_pad[i:i + blk, None]), all_ids[None, :],
+        ))
+        for i in range(0, u_sz + pad, blk)
+    ])[:u_sz]
+    n_d += float(d_all.size)
+    row_of = {int(u): i for i, u in enumerate(unreach)}
+
+    def dists_from(v: int) -> np.ndarray:
+        i = row_of.get(v)
+        if i is not None:
+            return d_all[i]
+        return np.asarray(backend.pair_dists(
+            jnp.full((1, 1), v, jnp.int32), all_ids[None, :],
+        ))[0]
+
+    grafted = np.zeros(adj_np.shape, bool)  # graft slots are permanent
+
+    def link(u: int, y: int, d: float) -> bool:
+        row = adj_np[y]
+        free = np.nonzero(row < 0)[0]
+        if free.size:
+            slot = int(free[0])
+        else:
+            evictable = np.nonzero(~grafted[y])[0]
+            if evictable.size == 0:
+                return False  # row is all grafts — caller picks another y
+            # evict the smallest-distance edge: its target sits in the
+            # dense local neighborhood with many alternative in-edges
+            slot = int(evictable[np.argmin(adj_d_np[y, evictable])])
+        adj_np[y, slot] = u
+        adj_d_np[y, slot] = d
+        grafted[y, slot] = True
+        return True
+
+    # Per island (forward-closure component): graft the best border
+    # pair (u*, y*) — min distance from any island member to any
+    # reachable vertex — then flood the island's closure as seen.
+    # Grafts never evict each other (no ping-pong), so every pass
+    # makes permanent progress; the outer BFS re-run heals nodes cut
+    # loose when a graft evicted their only in-edge.
+    for _ in range(64):
+        todo = np.nonzero(~seen)[0]
+        if todo.size == 0:
+            break
+        for u in todo:
+            while not seen[u]:
+                comp = bfs_reachable(adj_np, int(u)) & ~seen
+                members = np.nonzero(comp)[0]
+                d_sub = np.stack([dists_from(int(v)) for v in members])
+                d_sub = np.where(seen[None, :], d_sub, np.inf)
+                while True:
+                    flat = int(np.argmin(d_sub))
+                    ui, y = divmod(flat, n)
+                    if link(int(members[ui]), y, float(d_sub[ui, y])):
+                        break
+                    d_sub[:, y] = np.inf  # row saturated with grafts
+                seen |= bfs_reachable(adj_np, int(members[ui]))
+        seen = bfs_reachable(adj_np, entry)
+    adj0 = jnp.asarray(adj_np)
+    adj0_d = jnp.asarray(adj_d_np)
+    backend = backend.with_updated_edges(all_ids, adj0)
+    return adj0, adj0_d, backend, n_d

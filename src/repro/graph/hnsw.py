@@ -113,11 +113,12 @@ def _build_hnsw_bulk(
     if n >= 2:
         members = np.arange(n, dtype=np.int32)
         with obs.span("build/bulk_refine", layer=0) as sp:
-            pool_ids, pool_d, nd, nh, _ = bulk_refine(
+            pool_ids, pool_d, nd, nh, rounds = bulk_refine(
                 data, backend, members, r=params.r_base, params=params,
                 seed=seed, layer=0,
             )
             sp.add_cost(nd, nh)
+            sp.set(rounds=rounds)
         with obs.span("build/bulk_commit", layer=0):
             adj0, adj0_d, backend = bulk_commit(
                 engine, adj0, adj0_d, backend, jnp.asarray(members),
@@ -131,11 +132,12 @@ def _build_hnsw_bulk(
         if members.size < 2:
             continue  # nothing to link at this layer
         with obs.span("build/bulk_refine", layer=l) as sp:
-            pool_ids, pool_d, nd, nh, _ = bulk_refine(
+            pool_ids, pool_d, nd, nh, rounds = bulk_refine(
                 data, backend, members, r=params.r_upper, params=params,
                 seed=seed, layer=l,
             )
             sp.add_cost(nd, nh)
+            sp.set(rounds=rounds)
         with obs.span("build/bulk_commit", layer=l):
             a, ad, backend = bulk_commit(
                 engine, adj_up[l - 1], adj_up_d[l - 1], backend,
@@ -148,12 +150,10 @@ def _build_hnsw_bulk(
 
     entry = int(np.argmax(levels_np)) if n else 0
     lv = jnp.asarray(levels_np)
-    with obs.span("build/repair") as sp:
-        adj0, adj0_d, adj_up, adj_up_d, backend, rd, rh = repair_reachability(
-            data, adj0, adj0_d, adj_up, adj_up_d, backend, lv, entry,
-            params=params,
-        )
-        sp.add_cost(rd, rh)
+    adj0, adj0_d, adj_up, adj_up_d, backend, rd, rh = repair_reachability(
+        data, adj0, adj0_d, adj_up, adj_up_d, backend, lv, entry,
+        params=params,
+    )
     bulk_nd = n_d
     n_d += rd
     n_h += rh

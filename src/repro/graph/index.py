@@ -308,7 +308,6 @@ class AnnIndex:
                 f"unknown build strategy {strategy!r}; "
                 "valid: 'bulk', 'incremental'"
             )
-        data = jnp.asarray(data, jnp.float32)
         params = spec.default_params if params is None else params
         if isinstance(backend, str):
             if backend not in bk.kinds():
@@ -316,10 +315,6 @@ class AnnIndex:
                     f"unknown backend kind {backend!r}; valid kinds: "
                     f"{', '.join(bk.kinds())}"
                 )
-            kw = dict(backend_kwargs or {})
-            if backend == "flash_blocked":
-                kw.setdefault("r_for_blocked", params.r_base)
-            be = bk.make_backend(backend, data, jax.random.PRNGKey(seed), **kw)
             kind = backend
         else:
             if backend_kwargs:
@@ -329,10 +324,20 @@ class AnnIndex:
                 )
             be = backend
             kind = _KIND_OF_TYPE.get(type(backend), "custom")
+        # the root span holds the coder's fit and encode too: its host work
+        # is part of what a build costs
         with obs.span(
-            "build", algo=algo, strategy=strategy, backend=kind,
-            n=int(data.shape[0]),
+            "build", algo=algo, strategy=strategy, backend=kind, n=len(data),
         ) as sp:
+            data = jnp.asarray(data, jnp.float32)
+            if isinstance(backend, str):
+                kw = dict(backend_kwargs or {})
+                if backend == "flash_blocked":
+                    kw.setdefault("r_for_blocked", params.r_base)
+                with obs.span("build/coder", kind=kind):
+                    be = bk.make_backend(
+                        backend, data, jax.random.PRNGKey(seed), **kw
+                    )
             graph, raw_stats = spec.builder(
                 data, be, params, seed, strategy=strategy, **algo_kwargs
             )

@@ -114,10 +114,11 @@ def _build_nsg_bulk(data, backend, entry, *, params: BuildParams,
     if n >= 2:
         members = np.arange(n, dtype=np.int32)
         with obs.span("build/bulk_refine", layer=0) as sp:
-            pool_ids, pool_d, n_d, n_h, _ = bulk_refine(
+            pool_ids, pool_d, n_d, n_h, rounds = bulk_refine(
                 data, backend, members, r=r, params=flat, seed=seed, layer=0
             )
             sp.add_cost(n_d, n_h)
+            sp.set(rounds=rounds)
         with obs.span("build/bulk_commit", layer=0):
             adj, adj_d, backend = bulk_commit(
                 engine, adj, adj_d, backend, jnp.asarray(members),
@@ -132,12 +133,10 @@ def _build_nsg_bulk(data, backend, entry, *, params: BuildParams,
     adj_up = jnp.full((0, n, flat.r_upper), -1, jnp.int32)
     adj_up_d = jnp.full((0, n, flat.r_upper), INF)
     levels = jnp.zeros((n,), jnp.int32)
-    with obs.span("build/repair") as sp:
-        adj, adj_d, adj_up, adj_up_d, backend, rd, rh = repair_reachability(
-            data, adj, adj_d, adj_up, adj_up_d, backend, levels, int(entry),
-            params=flat,
-        )
-        sp.add_cost(rd, rh)
+    adj, adj_d, adj_up, adj_up_d, backend, rd, rh = repair_reachability(
+        data, adj, adj_d, adj_up, adj_up_d, backend, levels, int(entry),
+        params=flat,
+    )
     del rd, rh  # FlatIndex carries no stats; counters kept for symmetry
     return FlatIndex(adj=adj, adj_d=adj_d, entry=entry, backend=backend), knn_adj
 

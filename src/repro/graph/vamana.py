@@ -120,23 +120,22 @@ def _build_vamana_bulk(data, backend, entry, *, params: BuildParams, seed: int):
     if n >= 2:
         members = np.arange(n, dtype=np.int32)
         with obs.span("build/bulk_refine", layer=0) as sp:
-            pool_ids, pool_d, n_d, n_h, _ = bulk_refine(
+            pool_ids, pool_d, n_d, n_h, rounds = bulk_refine(
                 data, backend, members, r=flat.r_base, params=flat,
                 seed=seed, layer=0,
             )
             sp.add_cost(n_d, n_h)
+            sp.set(rounds=rounds)
         with obs.span("build/bulk_commit", layer=0):
             adj0, adj0_d, backend = bulk_commit(
                 engine, adj0, adj0_d, backend, jnp.asarray(members),
                 pool_ids, pool_d, r=flat.r_base,
             )
 
-    with obs.span("build/repair") as sp:
-        adj0, adj0_d, adj_up, adj_up_d, backend, rd, rh = repair_reachability(
-            data, adj0, adj0_d, adj_up, adj_up_d, backend, levels, int(entry),
-            params=flat,
-        )
-        sp.add_cost(rd, rh)
+    adj0, adj0_d, adj_up, adj_up_d, backend, rd, rh = repair_reachability(
+        data, adj0, adj0_d, adj_up, adj_up_d, backend, levels, int(entry),
+        params=flat,
+    )
     index = FlatIndex(adj=adj0, adj_d=adj0_d, entry=entry, backend=backend)
     return index, CostAccount(
         n_dists=jnp.float32(n_d + rd), n_hops=jnp.float32(n_h + rh),

@@ -13,12 +13,14 @@ Two tiers of instrumentation:
     dispatches). They are as cheap as the ad-hoc counters they replaced —
     one locked increment or deque append per event, references held
     directly so the hot path never formats a label.
-  * **Gated extras** — spans, trace export, kernel-dispatch counters, and
-    build-phase counters — cost nothing unless the module-level enable
-    flag is set (``REPRO_OBS=1`` env, or :func:`enable` at runtime):
-    :func:`tick` and :func:`span` check it before touching labels or the
-    clock, and never run inside jitted code (counters fold in at the same
-    host boundaries ``CostAccount`` already crosses).
+  * **Gated extras** — spans (on the profiler's clock, and annotated into
+    any ``jax.profiler`` capture), trace export, kernel-dispatch, compile
+    and build-phase counters — cost nothing unless the module-level
+    enable flag is set (``REPRO_OBS=1`` env, or :func:`enable` at
+    runtime): :func:`tick` and :func:`span` check it before touching
+    labels, the clock or the profiler, and never run inside jitted code
+    (counters fold in at the same host boundaries ``CostAccount`` already
+    crosses).
 
 This package imports nothing from ``repro.graph`` / ``repro.kernels`` /
 ``repro.serve`` (they all import it), except lazily inside the report CLI.
@@ -46,6 +48,7 @@ from repro.obs.trace import (  # noqa: F401
     now,
     span,
     spans,
+    trace_clock_ns,
 )
 
 __all__ = [
@@ -71,6 +74,7 @@ __all__ = [
     "span",
     "spans",
     "tick",
+    "trace_clock_ns",
 ]
 
 

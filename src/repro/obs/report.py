@@ -6,7 +6,7 @@ Three renderers over the observability layer's state:
     snapshot (counters, gauges, histogram count/sum/window percentiles).
   * :func:`phase_table` — the paper's "where does indexing time go" table
     from a build's per-phase distance split (``BuildStats.phases``) and the
-    recorded build spans' wall time.
+    recorded build spans' host time.
   * :func:`json_dump` — one structured JSON object (metrics + spans) for
     artifact upload / offline diffing.
 
@@ -57,8 +57,10 @@ def phase_table(stats, *, spans: list | None = None) -> str:
 
     ``stats`` is anything with ``n_dists`` and ``phases`` (a
     :class:`~repro.graph.engine.BuildStats`). When build spans are
-    available (obs enabled during the build), wall time per recorded span
-    name is appended below the phase rows.
+    available (obs enabled during the build), the host time of each
+    recorded span is appended below the phase rows. Spans never wait for
+    the device, so a span's host time is not its device time: that comes
+    from a device trace.
     """
     import numpy as np
 
@@ -83,7 +85,7 @@ def phase_table(stats, *, spans: list | None = None) -> str:
     out.append(f"exact partition: {exact}")
     if spans:
         out.append("")
-        out.append("span                     wall_s      n_dists")
+        out.append("span                     host_s      n_dists")
         for sp in spans:
             out.append(f"{sp.name:<22} {sp.dur_s:>9.3f} {sp.n_dists:>12.0f}")
     return "\n".join(out)
@@ -151,7 +153,10 @@ def main(argv: list[str] | None = None) -> int:
         ),
     )
     stats = index.last_stats
-    build_spans = list(_flatten_spans(_trace.spans("build")))
+    build_spans = [
+        sp for sp in _flatten_spans(_trace.spans("build"))
+        if sp.name != "jit/compile"
+    ]
     print(phase_table(stats, spans=build_spans))
 
     print(f"\n== serve ({args.queries} queries + mutations) ==")

@@ -13,7 +13,11 @@ Contracts:
      recorded, inputs never ``float()``-ed).
   4. A build's per-phase distance split partitions ``n_dists`` exactly,
      for both incremental and bulk strategies.
-  5. The serve stats surfaces stay registry-backed and API-compatible:
+  5. Spans run on the profiler's clock (``time.time_ns``), show on a
+     ``jax.profiler`` capture's host plane, touch neither clock nor
+     profiler when disabled, record each executable JAX makes as a
+     ``jit/compile`` child, and cover a bulk build's host phases.
+  6. The serve stats surfaces stay registry-backed and API-compatible:
      ``latency_window`` is a ctor knob, ``reset()`` exists on every
      stats() provider, and live Runtime counters agree with the registry
      series under concurrent submit threads + the scheduler thread.
@@ -21,10 +25,13 @@ Contracts:
 
 from __future__ import annotations
 
+import glob
 import io
 import json
 import threading
+import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -286,6 +293,120 @@ class TestSpans:
         obs.tick("gated_total", n=100, route="x")
         obs.enable()
         assert obs.REGISTRY.counter("gated_total", route="x").value == 2
+
+
+class TestProfilerClock:
+    def test_span_clock_is_time_ns(self, obs_on):
+        before = time.time_ns()
+        with obs.span("clocked"):
+            pass
+        after = time.time_ns()
+        sp = obs.spans("clocked")[-1]
+        assert before <= sp.t0_ns <= sp.t1_ns <= after
+        assert sp.dur_s == (sp.t1_ns - sp.t0_ns) / 1e9
+        d = sp.to_dict()
+        assert (d["t0_ns"], d["t1_ns"]) == (sp.t0_ns, sp.t1_ns)
+
+    def test_span_on_the_profilers_host_plane(self, obs_on, tmp_path):
+        from jax.profiler import ProfileData
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with obs.span("obs-test/phase"):
+                time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        sp = obs.spans("obs-test/phase")[-1]
+        (path,) = glob.glob(
+            str(tmp_path / "**" / "*.xplane.pb"), recursive=True
+        )
+        pd = ProfileData.from_file(path)
+        (env,) = [p for p in pd.planes if p.name == "Task Environment"]
+        start = dict(env.stats)["profile_start_time"]
+        events = [
+            ev for p in pd.planes if p.name.startswith("/host:")
+            for line in p.lines for ev in line.events
+            if ev.name == "obs-test/phase"
+        ]
+        assert len(events) == 1
+        assert abs(start + events[0].start_ns - sp.t0_ns) < 1e6
+        assert abs(events[0].duration_ns - (sp.t1_ns - sp.t0_ns)) < 1e6
+
+    def test_disabled_span_reads_no_clock_and_emits_nothing(
+        self, obs_off, monkeypatch
+    ):
+        from repro.obs import trace
+
+        def boom(*_a, **_k):
+            raise AssertionError("a disabled span touched a clock or the profiler")
+
+        monkeypatch.setattr(trace, "trace_clock_ns", boom)
+        monkeypatch.setattr(trace, "now", boom)
+        monkeypatch.setattr(trace, "TraceAnnotation", boom)
+        with obs.span("ghost", attr=1) as sp:
+            sp.set(x=2)
+        assert sp is obs.NULL_SPAN
+
+    def test_compile_is_a_child_span_and_counted(self, obs_on):
+        def obs_test_program(x):
+            return x * 3 + 1
+
+        counter = obs.REGISTRY.counter(
+            "jit_executables_total", program="jit(obs_test_program)"
+        )
+        before = counter.value
+        with obs.span("compiling"):
+            t0 = time.time_ns()
+            jax.jit(obs_test_program)(np.ones(7, np.float32))
+            t1 = time.time_ns()
+        sp = obs.spans("compiling")[-1]
+        kids = [
+            c for c in sp.children
+            if c.name == "jit/compile"
+            and c.attrs["program"] == "jit(obs_test_program)"
+        ]
+        assert len(kids) == 1
+        # time.time() seconds converted to the span clock
+        assert t0 - 1e6 <= kids[0].t0_ns <= kids[0].t1_ns <= t1 + 1e6
+        assert counter.value == before + 1
+        assert obs.REGISTRY.counter(
+            "jit_compile_seconds_total", program="jit(obs_test_program)"
+        ).value > 0
+
+    def test_bulk_build_span_tree(self, obs_on):
+        data = make_clustered(400, 32, seed=5)
+        AnnIndex.build(
+            data, algo="hnsw", strategy="bulk", params=PARAMS,
+            backend_kwargs=FLASH_KW,
+        )
+        root = obs.spans("build")[-1]
+
+        def names(sp):
+            return [c.name for c in sp.children if c.name != "jit/compile"]
+
+        top = names(root)
+        assert top[0] == "build/coder" and top[-1] == "build/repair"
+        assert set(top[1:-1]) == {"build/bulk_refine", "build/bulk_commit"}
+        (coder,) = [c for c in root.children if c.name == "build/coder"]
+        assert names(coder) == [
+            "build/coder/pca", "build/coder/kmeans", "build/coder/encode"
+        ]
+        refine = [c for c in root.children if c.name == "build/bulk_refine"]
+        assert all(c.attrs["rounds"] >= 1 for c in refine)
+        (repair,) = [c for c in root.children if c.name == "build/repair"]
+        assert names(repair)[0] == "build/repair/bfs"
+        assert "unreachable" in repair.children[0].attrs
+        assert repair.attrs["passes"] >= 0
+        for c in repair.children:
+            if c.name == "build/repair/reinsert":
+                assert c.attrs["schedule"] <= c.attrs["schedule_padded"]
+        # children lie inside their parents on one clock
+        for parent in [root, coder, repair]:
+            for c in parent.children:
+                if c.name != "jit/compile":
+                    assert parent.t0_ns <= c.t0_ns <= c.t1_ns <= parent.t1_ns
 
 
 class TestBuildPhases:
