@@ -294,19 +294,17 @@ def _drop_self(cand_ids, cand_d, new_ids):
 
     A fresh build can never acquire the vertex being inserted (it has no
     incoming edges yet), so this is bit-exact no-op there — the stable
-    argsort of an already-sorted list is the identity. Re-inserting an
+    sort of an already-sorted list is the identity. Re-inserting an
     EXISTING vertex (``repro.index`` compaction, DESIGN.md §8) does find
     itself at distance ~0, and without this mask would select itself as its
     own closest neighbor.
     """
     self_hit = cand_ids == new_ids[:, None]
-    d = jnp.where(self_hit, INF, cand_d)
-    ids = jnp.where(self_hit, -1, cand_ids)
-    order = jnp.argsort(d, axis=1)
-    return (
-        jnp.take_along_axis(ids, order, axis=1),
-        jnp.take_along_axis(d, order, axis=1),
+    d, ids = jax.lax.sort(
+        (jnp.where(self_hit, INF, cand_d), jnp.where(self_hit, -1, cand_ids)),
+        dimension=1, num_keys=1, is_stable=True,
     )
+    return ids, d
 
 
 # ---------------------------------------------------------------------------
@@ -457,9 +455,11 @@ class BuildEngine:
                 r_l = params.r_base if l == 0 else params.r_upper
                 elig = (cand_pool < i) & (levels[:p] >= l) & (levels[i] >= l)
                 d = jnp.where(elig, d_all, INF)
-                order = jnp.argsort(d)
-                ids_s = jnp.where(jnp.isfinite(d[order]), cand_pool[order], -1)
-                sel = self.select_one(backend, ids_s, d[order], r=r_l)
+                d_s, ids_s = jax.lax.sort(
+                    (d, cand_pool), num_keys=1, is_stable=True
+                )
+                ids_s = jnp.where(jnp.isfinite(d_s), ids_s, -1)
+                sel = self.select_one(backend, ids_s, d_s, r=r_l)
                 new_ids = jnp.full((1,), i, jnp.int32)
                 m1 = jnp.array([levels[i] >= l])
                 if l == 0:
@@ -828,6 +828,16 @@ def bulk_refine(
     )
 
 
+def _f32_sort_key(x):
+    """int32 keys in ``jax.lax.sort``'s order of the float32 ``x`` (−0
+    equal to +0, NaN last): the bits of the canonical float, with the
+    magnitude bits flipped below zero."""
+    x = jnp.where(x == 0, 0.0, x)
+    x = jnp.where(jnp.isnan(x), jnp.nan, x)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return i ^ ((i >> 31) & 0x7FFFFFFF)
+
+
 @functools.partial(jax.jit, static_argnames=("params",))
 def bulk_reverse(adj, adj_d, backend, members, sel_ids, sel_d,
                  *, params: BuildParams):
@@ -850,12 +860,18 @@ def bulk_reverse(adj, adj_d, backend, members, sel_ids, sel_d,
     dst = sel_ids.reshape(-1)
     dd = sel_d.reshape(-1)
     dstk = jnp.where(dst >= 0, dst, n)  # invalid edges bucket to sentinel n
-    # group by destination, ascending distance within each group: stable
-    # sort by d, then stable sort by destination
-    o1 = jnp.argsort(dd, stable=True)
-    o2 = jnp.argsort(dstk[o1], stable=True)
-    o = o1[o2]
-    dst_s, src_s, dd_s = dstk[o], src[o], dd[o]
+    # group by destination, ascending distance within each group: a stable
+    # sort by distance, then a stable sort by destination, each carrying the
+    # other arrays. All int32 (distances by their bits): with float operands
+    # the chip's compiler takes about twice as long on these m·r elements.
+    ddb = jax.lax.bitcast_convert_type(dd, jnp.int32)
+    _, dst_s, src_s, ddb = jax.lax.sort(
+        (_f32_sort_key(dd), dstk, src, ddb), num_keys=1, is_stable=True
+    )
+    dst_s, src_s, ddb = jax.lax.sort(
+        (dst_s, src_s, ddb), num_keys=1, is_stable=True
+    )
+    dd_s = jax.lax.bitcast_convert_type(ddb, jnp.float32)
     idx = jnp.arange(m * r)
     first = jnp.concatenate(
         [jnp.ones((1,), bool), dst_s[1:] != dst_s[:-1]]
@@ -876,10 +892,9 @@ def bulk_reverse(adj, adj_d, backend, members, sel_ids, sel_d,
     # dedup (x may already sit in y's row): sort by id, strike repeats
     badc = cand_ids < 0
     idkey = jnp.where(badc, jnp.int32(2**30), cand_ids)
-    order = jnp.argsort(idkey, axis=1, stable=True)
-    ids_s = jnp.take_along_axis(cand_ids, order, axis=1)
-    d_s = jnp.take_along_axis(
-        jnp.where(badc, INF, cand_d), order, axis=1
+    _, ids_s, d_s = jax.lax.sort(
+        (idkey, cand_ids, jnp.where(badc, INF, cand_d)),
+        dimension=1, num_keys=1, is_stable=True,
     )
     dup = jnp.concatenate(
         [jnp.zeros((n, 1), bool), ids_s[:, 1:] == ids_s[:, :-1]], axis=1
@@ -915,10 +930,10 @@ def bulk_commit(engine: BuildEngine, adj, adj_d, backend, members,
     p = engine.params
     # The random tail is appended unsorted — selection's greedy occlusion
     # walk needs candidates ascending by distance.
-    pool_d = jnp.where(pool_ids >= 0, pool_d, INF)
-    order = jnp.argsort(pool_d, axis=1)
-    pool_ids = jnp.take_along_axis(pool_ids, order, axis=1)
-    pool_d = jnp.take_along_axis(pool_d, order, axis=1)
+    pool_d, pool_ids = jax.lax.sort(
+        (jnp.where(pool_ids >= 0, pool_d, INF), pool_ids),
+        dimension=1, num_keys=1, is_stable=True,
+    )
     if p.select_mode == "heuristic":
         sel = jax.lax.map(
             lambda a: select_neighbors(

@@ -64,11 +64,13 @@ def select_neighbors(
         step, (jnp.zeros((c,), bool), jnp.int32(0)), jnp.arange(c)
     )
     # Extract ≤ r selected, keep ascending order (scan went in sorted order).
+    # The stable sort carries the ids with their keys: no index gathers.
     key = jnp.where(sel_mask, cand_dists, INF)
     kk = min(r, c)  # candidate list may be shorter than r (bootstrap batches)
-    _, idx = jax.lax.top_k(-key, kk)
-    ids = jnp.where(sel_mask[idx], cand_ids[idx], -1)
-    dists = jnp.where(sel_mask[idx], cand_dists[idx], INF)
+    dists, ids = jax.lax.sort(
+        (key, jnp.where(sel_mask, cand_ids, -1)), num_keys=1, is_stable=True
+    )
+    ids, dists = ids[:kk], dists[:kk]
     if kk < r:
         ids = jnp.concatenate([ids, jnp.full((r - kk,), -1, ids.dtype)])
         dists = jnp.concatenate([dists, jnp.full((r - kk,), INF)])
@@ -91,10 +93,8 @@ def prune_list(
     mode="farthest"  — keep the r closest (the cheap NSW-style variant; used
     as an ablation in the benchmarks).
     """
-    c = cand_ids.shape[0]
     d = jnp.where(cand_ids >= 0, cand_dists, INF)
-    order = jnp.argsort(d)
-    ids_s, d_s = cand_ids[order], d[order]
+    d_s, ids_s = jax.lax.sort((d, cand_ids), num_keys=1, is_stable=True)
     if mode == "farthest":
         ids = jnp.where(jnp.isfinite(d_s[:r]), ids_s[:r], -1)
         return Selection(
@@ -102,5 +102,4 @@ def prune_list(
         )
     if mode != "heuristic":
         raise ValueError(f"unknown prune mode {mode!r}")
-    del c
     return select_neighbors(backend, ids_s, d_s, r=r, alpha=alpha)

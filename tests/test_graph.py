@@ -24,8 +24,10 @@ from repro.graph.hnsw import (
 )
 from repro.graph.knn import average_distance_ratio, exact_knn, recall_at_k
 from repro.graph.nsg import build_nsg
-from repro.graph.select import select_neighbors
+from repro.graph import engine as eng
+from repro.graph.select import Selection, prune_list, select_neighbors
 from repro.graph.vamana import build_vamana, search_flat_result
+from tests.conftest import make_clustered
 
 PARAMS = HNSWParams(r_upper=8, r_base=16, ef=32, batch=16, max_layers=3)
 
@@ -320,3 +322,362 @@ class TestSegmented:
         np.testing.assert_array_equal(
             np.asarray(got.adj0), np.asarray(ref.index.adj0)
         )
+
+
+# ---------------------------------------------------------------------------
+# The build's sorts carry their ids and distances. The oracles below are the
+# formulation they replaced: a sort or ``top_k`` computes a permutation, and
+# indexing applies it.
+# ---------------------------------------------------------------------------
+
+INF = jnp.float32(jnp.inf)
+
+
+def _old_select_neighbors(backend, cand_ids, cand_dists, *, r, alpha=1.0):
+    c = cand_ids.shape[0]
+    valid = cand_ids >= 0
+    pair = backend.pair_table(jnp.where(valid, cand_ids, 0))
+    pair = jnp.where(valid[:, None] & valid[None, :], pair, INF)
+
+    def step(carry, i):
+        sel_mask, count = carry
+        conflict = jnp.any(sel_mask & (alpha * pair[i] < cand_dists[i]))
+        ok = valid[i] & ~conflict & (count < r)
+        return (sel_mask.at[i].set(ok), count + ok.astype(jnp.int32)), ok
+
+    (sel_mask, count), _ = jax.lax.scan(
+        step, (jnp.zeros((c,), bool), jnp.int32(0)), jnp.arange(c)
+    )
+    key = jnp.where(sel_mask, cand_dists, INF)
+    kk = min(r, c)
+    _, idx = jax.lax.top_k(-key, kk)
+    ids = jnp.where(sel_mask[idx], cand_ids[idx], -1)
+    dists = jnp.where(sel_mask[idx], cand_dists[idx], INF)
+    ids = jnp.concatenate([ids, jnp.full((r - kk,), -1, ids.dtype)])
+    dists = jnp.concatenate([dists, jnp.full((r - kk,), INF)])
+    return Selection(ids=ids, dists=dists, count=count)
+
+
+def _old_prune_list(backend, cand_ids, cand_dists, *, r, alpha=1.0,
+                    mode="heuristic"):
+    d = jnp.where(cand_ids >= 0, cand_dists, INF)
+    order = jnp.argsort(d)
+    ids_s, d_s = cand_ids[order], d[order]
+    if mode == "farthest":
+        ids = jnp.where(jnp.isfinite(d_s[:r]), ids_s[:r], -1)
+        return Selection(
+            ids=ids, dists=d_s[:r], count=jnp.sum((ids >= 0).astype(jnp.int32))
+        )
+    return _old_select_neighbors(backend, ids_s, d_s, r=r, alpha=alpha)
+
+
+def _old_drop_self(cand_ids, cand_d, new_ids):
+    self_hit = cand_ids == new_ids[:, None]
+    d = jnp.where(self_hit, INF, cand_d)
+    ids = jnp.where(self_hit, -1, cand_ids)
+    order = jnp.argsort(d, axis=1)
+    return (
+        jnp.take_along_axis(ids, order, axis=1),
+        jnp.take_along_axis(d, order, axis=1),
+    )
+
+
+def _old_bootstrap(engine, data, adj0, adj0_d, adj_up, adj_up_d, backend,
+                   levels):
+    params = engine.params
+    p = min(params.batch, data.shape[0])
+    cand_pool = jnp.arange(p, dtype=jnp.int32)
+
+    def body(i, carry):
+        adj0, adj0_d, adj_up, adj_up_d, backend = carry
+        d_all = backend.query_dists(backend.prepare_query(data[i]), cand_pool)
+        for l in range(params.max_layers - 1, -1, -1):
+            r_l = params.r_base if l == 0 else params.r_upper
+            elig = (cand_pool < i) & (levels[:p] >= l) & (levels[i] >= l)
+            d = jnp.where(elig, d_all, INF)
+            order = jnp.argsort(d)
+            ids_s = jnp.where(jnp.isfinite(d[order]), cand_pool[order], -1)
+            sel = engine.select_one(backend, ids_s, d[order], r=r_l)
+            new_ids = jnp.full((1,), i, jnp.int32)
+            m1 = jnp.array([levels[i] >= l])
+            a, ad = (adj0, adj0_d) if l == 0 else (adj_up[l - 1], adj_up_d[l - 1])
+            a, ad, backend = engine.commit_forward(
+                a, ad, backend, new_ids, sel.ids[None], sel.dists[None], m1
+            )
+            a, ad, backend = engine.reverse_pass(
+                a, ad, backend, new_ids, sel.ids[None], sel.dists[None], m1
+            )
+            if l == 0:
+                adj0, adj0_d = a, ad
+            else:
+                adj_up = adj_up.at[l - 1].set(a)
+                adj_up_d = adj_up_d.at[l - 1].set(ad)
+        return adj0, adj0_d, adj_up, adj_up_d, backend
+
+    return jax.lax.fori_loop(
+        0, p, body, (adj0, adj0_d, adj_up, adj_up_d, backend)
+    )
+
+
+def _old_bulk_reverse(adj, adj_d, backend, members, sel_ids, sel_d, *,
+                      params):
+    m, r = sel_ids.shape
+    n = adj.shape[0]
+    k_cap = 2 * r
+    src = jnp.repeat(members, r)
+    dst = sel_ids.reshape(-1)
+    dd = sel_d.reshape(-1)
+    dstk = jnp.where(dst >= 0, dst, n)
+    o1 = jnp.argsort(dd, stable=True)
+    o2 = jnp.argsort(dstk[o1], stable=True)
+    o = o1[o2]
+    dst_s, src_s, dd_s = dstk[o], src[o], dd[o]
+    idx = jnp.arange(m * r)
+    first = jnp.concatenate([jnp.ones((1,), bool), dst_s[1:] != dst_s[:-1]])
+    rank = idx - jax.lax.cummax(jnp.where(first, idx, 0))
+    ok = (dst_s < n) & (rank < k_cap)
+    row = jnp.where(ok, dst_s, n)
+    col = jnp.where(ok, rank, 0)
+    prop_ids = jnp.full((n, k_cap), -1, jnp.int32).at[row, col].set(
+        src_s, mode="drop"
+    )
+    prop_d = jnp.full((n, k_cap), INF).at[row, col].set(dd_s, mode="drop")
+    touched = prop_ids[:, 0] >= 0
+    cand_ids = jnp.concatenate([adj, prop_ids], axis=1)
+    cand_d = jnp.concatenate([adj_d, prop_d], axis=1)
+    badc = cand_ids < 0
+    idkey = jnp.where(badc, jnp.int32(2**30), cand_ids)
+    order = jnp.argsort(idkey, axis=1, stable=True)
+    ids_s = jnp.take_along_axis(cand_ids, order, axis=1)
+    d_s = jnp.take_along_axis(jnp.where(badc, INF, cand_d), order, axis=1)
+    dup = jnp.concatenate(
+        [jnp.zeros((n, 1), bool), ids_s[:, 1:] == ids_s[:, :-1]], axis=1
+    )
+    ids_s = jnp.where(dup, -1, ids_s)
+    d_s = jnp.where(dup, INF, d_s)
+    pruned = jax.lax.map(
+        lambda a: _old_prune_list(
+            backend, *a, r=r, alpha=params.bulk_select_alpha(),
+            mode=params.prune_mode,
+        ),
+        (ids_s, d_s), batch_size=eng._COMMIT_ROWS,
+    )
+    new_adj = jnp.where(touched[:, None], pruned.ids, adj)
+    new_adj_d = jnp.where(touched[:, None], pruned.dists, adj_d)
+    backend = backend.with_updated_edges(jnp.arange(n, dtype=jnp.int32), new_adj)
+    return new_adj, new_adj_d, backend
+
+
+def _old_bulk_commit(engine, adj, adj_d, backend, members, pool_ids, pool_d,
+                     *, r):
+    p = engine.params
+    pool_d = jnp.where(pool_ids >= 0, pool_d, INF)
+    order = jnp.argsort(pool_d, axis=1)
+    pool_ids = jnp.take_along_axis(pool_ids, order, axis=1)
+    pool_d = jnp.take_along_axis(pool_d, order, axis=1)
+    sel = jax.lax.map(
+        lambda a: _old_select_neighbors(
+            backend, *a, r=r, alpha=p.bulk_select_alpha()
+        ),
+        (pool_ids, pool_d), batch_size=eng._COMMIT_ROWS,
+    )
+    mask = jnp.ones(members.shape, bool)
+    adj, adj_d, backend = eng.commit_forward(
+        adj, adj_d, backend, members, sel.ids, sel.dists, mask
+    )
+    return _old_bulk_reverse(
+        adj, adj_d, backend, members, sel.ids, sel.dists, params=p
+    )
+
+
+SORT_N, SORT_R = 480, 8
+SORT_PARAMS = HNSWParams(r_upper=4, r_base=SORT_R, ef=16, batch=24, max_layers=3)
+
+
+@pytest.fixture(scope="module")
+def tie_backend(key):
+    """flash_blocked over 4 subspaces: integer distances, many of them tied."""
+    data = jnp.asarray(make_clustered(SORT_N, 16, n_clusters=6, seed=3))
+    be = graph.make_backend(
+        "flash_blocked", data, key, d_f=16, m_f=4, l_f=4, h=8,
+        kmeans_iters=4, r_for_blocked=SORT_R,
+    )
+    return data, be
+
+
+def _candidates(be, data, rng, rows, c, *, sort):
+    """Candidate rows drawn with repeats (exact ties), −1 holes at +inf."""
+    ids = rng.integers(0, SORT_N, size=(rows, c)).astype(np.int32)
+    ids[rng.random((rows, c)) < 0.2] = -1
+    q = rng.integers(0, SORT_N, size=rows)
+    d = np.asarray(jax.vmap(
+        lambda qi, ci: be.query_dists(be.prepare_query(data[qi]), ci)
+    )(jnp.asarray(q), jnp.asarray(np.maximum(ids, 0))))
+    d = np.where(ids >= 0, d, np.inf).astype(np.float32)
+    if sort:
+        o = np.argsort(d, axis=1, kind="stable")
+        ids, d = np.take_along_axis(ids, o, 1), np.take_along_axis(d, o, 1)
+    return jnp.asarray(ids), jnp.asarray(d), q
+
+
+def _case_select_neighbors(be, data, rng):
+    out = []
+    for c, r in [(24, 8), (12, 16)]:  # r > c: the padded tail
+        ids, d, _ = _candidates(be, data, rng, 64, c, sort=True)
+        for f in (select_neighbors, _old_select_neighbors):
+            out.append(jax.vmap(lambda i, x: f(be, i, x, r=r, alpha=1.2))(ids, d))
+    return out[0::2], out[1::2]
+
+
+def _case_prune_list(be, data, rng):
+    ids, d, _ = _candidates(be, data, rng, 64, 17, sort=False)
+    new, old = [], []
+    for mode in ("heuristic", "farthest"):
+        for f, acc in ((prune_list, new), (_old_prune_list, old)):
+            acc.append(jax.vmap(
+                lambda i, x: f(be, i, x, r=SORT_R, alpha=1.0, mode=mode)
+            )(ids, d))
+    return new, old
+
+
+def _case_drop_self(be, data, rng):
+    ids, d, q = _candidates(be, data, rng, 64, 24, sort=True)
+    new_ids = jnp.asarray(q, jnp.int32)
+    ids = ids.at[:, 3].set(new_ids).at[:, 7].set(new_ids)  # self hits
+    return eng._drop_self(ids, d, new_ids), _old_drop_self(ids, d, new_ids)
+
+
+def _graph_state(params, n):
+    l_up = params.max_layers - 1
+    return (
+        jnp.full((n, params.r_base), -1, jnp.int32),
+        jnp.full((n, params.r_base), INF),
+        jnp.full((l_up, n, params.r_upper), -1, jnp.int32),
+        jnp.full((l_up, n, params.r_upper), INF),
+    )
+
+
+def _case_bootstrap(be, data, rng):
+    engine = eng.BuildEngine(SORT_PARAMS)
+    levels = jnp.asarray(
+        sample_levels(5, SORT_N, r_upper=4, max_layers=3)
+    )
+    state = _graph_state(SORT_PARAMS, SORT_N)
+    new = jax.jit(lambda *a: engine.bootstrap(*a)[:5])(data, *state, be, levels)
+    old = jax.jit(lambda *a: _old_bootstrap(engine, *a))(data, *state, be, levels)
+    return new, old
+
+
+def _reverse_inputs(be, rng, *, hot):
+    """Forward lists of 320 members; ``hot`` of them draw from 12 destinations
+    (groups longer than 2R, cut among tied distances), and each member's
+    destination row already lists it at a random slot (the dedup)."""
+    m, n, r = 320, SORT_N, SORT_R
+    members = np.sort(rng.choice(n, m, replace=False)).astype(np.int32)
+    sel = np.stack([rng.choice(n, r, replace=False) for _ in range(m)])
+    for i in range(hot):
+        sel[i] = rng.choice(12, r, replace=False)
+    sel[rng.random((m, r)) < 0.15] = -1
+    sel_d = np.where(sel >= 0, rng.integers(0, 4, (m, r)), np.inf)
+    adj = np.full((n, r), -1, np.int32)
+    adj_d = np.full((n, r), np.inf, np.float32)
+    for x, row in zip(members[::3], sel[::3]):
+        y = row[row >= 0][:1]
+        if y.size:
+            slot = rng.integers(0, r)
+            adj[y[0], slot], adj_d[y[0], slot] = x, rng.integers(0, 4)
+    return (jnp.asarray(adj), jnp.asarray(adj_d), be, jnp.asarray(members),
+            jnp.asarray(sel, jnp.int32), jnp.asarray(sel_d, jnp.float32))
+
+
+def _case_bulk_reverse_grouping(be, data, rng):
+    args = _reverse_inputs(be, rng, hot=160)
+    new = eng.bulk_reverse(*args, params=SORT_PARAMS)
+    old = jax.jit(
+        lambda *a: _old_bulk_reverse(*a, params=SORT_PARAMS)
+    )(*args)
+    return new, old
+
+
+def _case_bulk_reverse_dedup(be, data, rng):
+    args = _reverse_inputs(be, rng, hot=0)
+    new = eng.bulk_reverse(*args, params=SORT_PARAMS)
+    old = jax.jit(
+        lambda *a: _old_bulk_reverse(*a, params=SORT_PARAMS)
+    )(*args)
+    return new, old
+
+
+def _case_f32_sort_key(be, data, rng):
+    """The grouping's integer key orders float32 as the float sort does:
+    −0 tied with +0, subnormals, negatives, ±inf, NaN last."""
+    x = np.float32([0.0, -0.0, 1e-45, -1e-45, 1.0, -1.5, 2.0, np.inf, -np.inf,
+                    np.nan, -np.nan, 3.4e38, -3.4e38])
+    x = jnp.asarray(x[rng.integers(0, x.size, 512)])
+    ids = jnp.arange(x.size, dtype=jnp.int32)
+    _, new_ids, new_x = jax.lax.sort(
+        (eng._f32_sort_key(x), ids, x), num_keys=1, is_stable=True
+    )
+    order = jnp.argsort(x, stable=True)
+    return (new_ids, new_x), (ids[order], x[order])
+
+
+def _commit_both(be, members, pool_ids, pool_d, r):
+    engine = eng.BuildEngine(SORT_PARAMS)
+    adj = jnp.full((SORT_N, r), -1, jnp.int32)
+    adj_d = jnp.full((SORT_N, r), INF)
+    args = (adj, adj_d, be, members, pool_ids, pool_d)
+    new = eng.bulk_commit(engine, *args, r=r)
+    old = jax.jit(
+        lambda *a: _old_bulk_commit(engine, *a, r=r)
+    )(*args)
+    return new, old
+
+
+def _case_bulk_commit_presort(be, data, rng):
+    """An unsorted pool over every fourth vertex (an upper layer's commit)."""
+    members = jnp.arange(0, SORT_N, 4, dtype=jnp.int32)
+    ids, d, _ = _candidates(be, data, rng, members.shape[0], 20, sort=False)
+    return _commit_both(be, members, ids, d, SORT_PARAMS.r_upper)
+
+
+def _case_bulk_commit_build(be, data, rng):
+    """Layer 0 of a bulk build: refined pools, then the commit, mirror too."""
+    members = np.arange(SORT_N, dtype=np.int32)
+    pool_ids, pool_d, *_ = eng.bulk_refine(
+        data, be, members, r=SORT_R, params=SORT_PARAMS, seed=7
+    )
+    return _commit_both(be, jnp.asarray(members), pool_ids, pool_d, SORT_R)
+
+
+SORT_CASES = {
+    "select_neighbors": _case_select_neighbors,
+    "prune_list": _case_prune_list,
+    "drop_self": _case_drop_self,
+    "bootstrap": _case_bootstrap,
+    "bulk_reverse_grouping": _case_bulk_reverse_grouping,
+    "bulk_reverse_dedup": _case_bulk_reverse_dedup,
+    "f32_sort_key": _case_f32_sort_key,
+    "bulk_commit_presort": _case_bulk_commit_presort,
+    "bulk_commit_build": _case_bulk_commit_build,
+}
+
+
+def _bits(x):
+    """Floats by their bits: −0 is not +0, and NaN equals itself."""
+    x = np.asarray(x)
+    return x.view(np.int32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("site", list(SORT_CASES))
+def test_payload_sort_bit_exact(tie_backend, site):
+    """Each sort that carries ids and distances gives, bit for bit, what the
+    argsort / top_k and gathers it replaced gave — ties, −1 ids and +inf
+    padding included; for the commit, adjacency, distances and mirror."""
+    data, be = tie_backend
+    new, old = SORT_CASES[site](be, data, np.random.default_rng(11))
+    leaves_new = jax.tree.leaves(new)
+    leaves_old = jax.tree.leaves(old)
+    assert len(leaves_new) == len(leaves_old) > 0
+    for a, b in zip(leaves_new, leaves_old):
+        np.testing.assert_array_equal(_bits(a), _bits(b))

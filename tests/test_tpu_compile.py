@@ -1,4 +1,5 @@
-"""Compile rehearsals: every Pallas kernel, compiled for a described TPU v5e.
+"""Compile rehearsals: every Pallas kernel, and the build's commit program,
+compiled for a described TPU v5e.
 
 Interpret-mode parity (test_kernels.py, test_expand.py) cannot see what the
 TPU compiler refuses: blocks not aligned to the (8, 128) tiling, reductions
@@ -19,9 +20,12 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import graph
+from repro.graph.engine import BuildEngine, BuildParams, bulk_commit
 from repro.kernels import ops
 
 N, R, M, K, W, C, D = 1_000_000, 32, 16, 16, 8, 96, 768
@@ -143,3 +147,24 @@ def test_sq_l2(one_chip):
         ((N, D), jnp.int32),
         ((D,), jnp.float32),
     )
+
+
+def test_bulk_commit_applies_no_permutation_by_gather(one_chip):
+    """Every sort in the layer-0 commit carries its ids and distances: the
+    compiled program holds no ``take_along_axis`` and no ``top_k``, whose
+    element gathers a TPU runs about one element at a time."""
+    n, pool, r = 1024, 96, 32  # deep-96's layer-0 widths, coder M=48
+    x = np.random.default_rng(0).normal(size=(n, 96)).astype(np.float32)
+    be = graph.make_backend(
+        "flash_blocked", x, d_f=96, m_f=48, kmeans_iters=1, r_for_blocked=r
+    )
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = bulk_commit.lower(
+        BuildEngine(BuildParams()),
+        s((n, r), jnp.int32), s((n, r), jnp.float32),
+        jax.tree.map(lambda a: s(a.shape, a.dtype), be),
+        s((n,), jnp.int32), s((n, pool), jnp.int32), s((n, pool), jnp.float32),
+        r=r,
+    ).compile().as_text()
+    for op in ("take_along_axis", "top_k", "argsort"):
+        assert op not in text, f"{op} in the compiled bulk_commit"
