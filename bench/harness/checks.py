@@ -3,9 +3,9 @@
 Each check is a number beside its limit. A build is judged by its graph
 (ids in range, no self-edges, every vertex reachable from the entry on the
 base layer) and by what a search of it returns; a served request by its
-answer. Answers are compared with :mod:`reference`: the true top-k for
-recall, and for each returned id its exact distance, which an exact rerank
-has to reproduce.
+answer. Answers are compared with :mod:`reference`, in the configuration's
+distance: the true top-k for recall, and for each returned id its exact
+distance, which an exact rerank has to reproduce.
 """
 
 from __future__ import annotations
@@ -81,30 +81,43 @@ def malformed(ids: np.ndarray, dists: np.ndarray, n: int) -> int:
     return bad
 
 
-def dist_gap(ids, dists, data, queries) -> float:
+def dist_gap(ids, dists, data, queries, *, distance: str = "l2") -> float:
     """Widest gap between a returned distance and the exact one of the same
-    id, as a share of the exact one (floored at a thousandth of the median
-    exact distance)."""
-    ref = reference.distances(data, queries, ids)
+    id. Under ``"l2"`` as a share of the exact one (floored at a thousandth
+    of the median exact distance); under ``"ip"`` as a share of
+    ||q||·||x_id||, the largest value <q, x_id> can take, since the rounding
+    of a float32 dot product scales with that and not with <q, x_id>, which
+    can be negative or near 0."""
+    ref = reference.distances(data, queries, ids, distance=distance)
     ok = np.isfinite(ref) & (ids >= 0)
     if not ok.any():
         return float("inf")
-    floor = 1e-3 * float(np.median(ref[ok]))
     got = np.asarray(dists, np.float64)
+    if distance == "ip":
+        rows = np.asarray(data)[np.maximum(ids, 0)].astype(np.float64)
+        q = np.asarray(queries, np.float64)
+        scale = np.linalg.norm(q, axis=1)[:, None] * np.linalg.norm(rows, axis=-1)
+        # an id at the origin: its exact distance is 0, and only 0 is no gap
+        scale = np.maximum(scale, np.finfo(np.float64).tiny)
+    else:
+        scale = np.maximum(ref, 1e-3 * float(np.median(ref[ok])))
     gap = np.full(ref.shape, np.inf)
-    gap[ok] = np.abs(got[ok] - ref[ok]) / np.maximum(ref[ok], floor)
+    gap[ok] = np.abs(got[ok] - ref[ok]) / scale[ok]
     return float(gap.max())
 
 
-def answer_checks(ids, dists, data, queries, truth, limits: dict) -> list[Check]:
-    """Searched or served answers against the reference."""
+def answer_checks(ids, dists, data, queries, truth, limits: dict, *,
+                  distance: str = "l2") -> list[Check]:
+    """Searched or served answers against the reference, in the
+    configuration's ``distance``."""
     ids = np.asarray(ids)
     dists = np.asarray(dists)
     k = truth.shape[1]
     return [
         Check("malformed", float(malformed(ids[:, :k], dists[:, :k], data.shape[0])),
               0.0, "<="),
-        Check("dist_gap", dist_gap(ids[:, :k], dists[:, :k], data, queries),
+        Check("dist_gap", dist_gap(ids[:, :k], dists[:, :k], data, queries,
+                                   distance=distance),
               float(limits["dist_gap"]), "<="),
         Check("recall_at_10", reference.recall_at_k(ids[:, :k], truth),
               float(limits["recall_at_10"]), ">="),

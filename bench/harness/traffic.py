@@ -78,12 +78,14 @@ def build_fn(cfg: dict) -> Callable:
 
 def search_answers(index, queries, cfg: dict, control: bool, base):
     """Ids and distances the searched graph returns; under ``control`` the
-    reference in bfloat16 takes the program's place."""
+    reference in bfloat16, in the configuration's distance, takes the
+    program's place."""
     from repro.index import SearchSpec
 
     spec = SearchSpec(**cfg["search"])
     if control:
-        return reference.topk(base, queries, spec.k, precision="bfloat16")
+        return reference.topk(base, queries, spec.k, distance=reference.distance_of(cfg),
+                              precision="bfloat16")
     res = index.search(queries, spec=spec)
     return np.asarray(res.ids), np.asarray(res.dists)
 
@@ -95,6 +97,7 @@ def build_stream(ctx: Context) -> Outcome:
     by held-out queries."""
     cfg, tr = ctx.config, ctx.traffic
     n = int(cfg["n"])
+    distance = reference.distance_of(cfg)
     # a configuration whose searches are slow may check fewer queries
     n_check = int(cfg.get("check_queries", tr["check_queries"]))
     base, queries = data.inputs(cfg, ctx.seed, n_check)
@@ -134,8 +137,9 @@ def build_stream(ctx: Context) -> Outcome:
     )
     ids, dists = search_answers(index, queries, cfg, ctx.control, base)
     del index, g
-    truth, _ = reference.topk(base, queries, int(cfg["search"]["k"]))
-    found += checks.answer_checks(ids, dists, base, queries, truth, cfg["limits"])
+    truth, _ = reference.topk(base, queries, int(cfg["search"]["k"]), distance=distance)
+    found += checks.answer_checks(ids, dists, base, queries, truth, cfg["limits"],
+                                  distance=distance)
     recall = next(c.value for c in found if c.name == "recall_at_10")
     e2e = {"setup_s": setup_s, "recall_at_10": recall}
     if window is not None:
@@ -182,6 +186,7 @@ def open_loop(ctx: Context) -> Outcome:
 
     cfg, tr = ctx.config, ctx.traffic
     n = int(cfg["n"])
+    distance = reference.distance_of(cfg)
     seconds = float(tr["trace_seconds"]) if ctx.tracing else ctx.seconds
     due = arrival_offsets(ctx.seed, float(tr["rate"]), seconds)
     base, queries = data.inputs(cfg, ctx.seed, int(due.size))
@@ -257,9 +262,11 @@ def open_loop(ctx: Context) -> Outcome:
     dists = np.stack([np.asarray(answers[i].dists).reshape(-1)[:k] for i in ok]) if ok else np.zeros((0, k), np.float32)
     del index, rt
     if ctx.control:
-        ids, dists = reference.topk(base, queries[ok], k, precision="bfloat16")
-    truth, _ = reference.topk(base, queries[ok], k)
-    found = checks.answer_checks(ids, dists, base, queries[ok], truth, cfg["limits"])
+        ids, dists = reference.topk(base, queries[ok], k, distance=distance,
+                                    precision="bfloat16")
+    truth, _ = reference.topk(base, queries[ok], k, distance=distance)
+    found = checks.answer_checks(ids, dists, base, queries[ok], truth, cfg["limits"],
+                                 distance=distance)
     recall = next(c.value for c in found if c.name == "recall_at_10")
     buckets = serve.DEFAULT_BUCKETS
     return Outcome(

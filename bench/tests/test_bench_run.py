@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench_checkout import BENCH, ROOT, last_json, run_cell, write_checkout
+from bench_checkout import BENCH, ROOT, TINY_CONFIG, last_json, run_cell, write_checkout
 
 import run  # noqa: E402 — bench/run.py, on the path through bench_checkout
 
@@ -129,6 +129,70 @@ def test_broken_timed_path_is_not_correct(tiny_root, capsys, monkeypatch,
 
 def test_bfloat16_control_is_not_correct(tiny_root, capsys):
     rc, res, err = run_cell(capsys, tiny_root, "tiny-build", "--control", "1")
+    assert rc == 0, err
+    assert res["correct"] is False
+    assert res["checks"]["dist_gap"]["value"] > res["checks"]["dist_gap"]["limit"]
+
+
+#: an inner-product deployment with out-of-distribution queries, in
+#: miniature: rows not unit-normalised, queries of their own spectrum and
+#: shifted off the rows, as text queries over image embeddings are
+TINY_IP_CONFIG = dict(
+    TINY_CONFIG, metric="inner product", distance="ip",
+    queries={"spectrum": "linear", "scale_hi": 0.2, "scale_lo": 1.0, "offset": 2.0},
+)
+
+
+@pytest.fixture(scope="module")
+def ip_root(tmp_path_factory):
+    root = str(tmp_path_factory.mktemp("ip_checkout"))
+    write_checkout(root, config=TINY_IP_CONFIG)
+    return root
+
+
+@pytest.mark.parametrize("cell", ["tiny-build", "tiny-serve"])
+def test_inner_product_cell_holds_the_l2_program_to_its_metric(ip_root, capsys, cell):
+    """A configuration that states ``"distance": "ip"`` and a ``queries``
+    block, dropped into a checkout as files alone, runs by name, built or
+    served; the program, which ranks by squared L2 only, comes out not
+    correct."""
+    rc, res, err = run_cell(capsys, ip_root, cell)
+    assert rc == 0, err
+    assert res["correct"] is False
+    found = res["checks"]
+    assert found["malformed"]["value"] == 0 and res["failed"] == 0
+    assert found["dist_gap"]["value"] > found["dist_gap"]["limit"], found
+    # the squared-L2 top-10 is another answer on rows of unequal norms
+    assert found["recall_at_10"]["value"] < found["recall_at_10"]["limit"], found
+
+
+def _ranked_by_inner_product(search):
+    def fake(self, queries, *a, **kw):
+        res = search(self, queries, *a, **kw)
+        k = res.ids.shape[1]
+        d = -np.asarray(queries, np.float32) @ np.asarray(self._data, np.float32).T
+        ids = np.argsort(d, axis=1, kind="stable")[:, :k]
+        return res._replace(ids=jnp.asarray(ids, jnp.int32),
+                            dists=jnp.asarray(np.take_along_axis(d, ids, axis=1)))
+    return fake
+
+
+def test_inner_product_cell_passes_answers_ranked_by_inner_product(
+        ip_root, capsys, monkeypatch):
+    """The same cell with the search's answers replaced by a float32 brute
+    force by inner product: correct, so the cell can be passed."""
+    from repro.index import AnnIndex
+
+    monkeypatch.setattr(AnnIndex, "search", _ranked_by_inner_product(AnnIndex.search))
+    rc, res, err = run_cell(capsys, ip_root, "tiny-build")
+    assert rc == 0, err
+    assert res["correct"] is True, res["checks"]
+    assert res["checks"]["recall_at_10"]["value"] == 1.0
+
+
+@pytest.mark.parametrize("cell", ["tiny-build", "tiny-serve"])
+def test_inner_product_control_is_not_correct(ip_root, capsys, cell):
+    rc, res, err = run_cell(capsys, ip_root, cell, "--control", "1")
     assert rc == 0, err
     assert res["correct"] is False
     assert res["checks"]["dist_gap"]["value"] > res["checks"]["dist_gap"]["limit"]
